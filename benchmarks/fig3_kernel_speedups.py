@@ -124,10 +124,10 @@ def run() -> list[tuple]:
     n_pages = 1 + B * n_pp
     nb = hd // quant_block(hd)
     pool = {
-        "k_codes": jnp.zeros((n_pages, ps, hkv, hd // 2), jnp.uint8),
-        "k_scales": jnp.full((n_pages, ps, hkv, nb), 127, jnp.uint8),
-        "v_codes": jnp.zeros((n_pages, ps, hkv, hd // 2), jnp.uint8),
-        "v_scales": jnp.full((n_pages, ps, hkv, nb), 127, jnp.uint8),
+        "k_codes": jnp.zeros((n_pages, hkv, ps, hd // 2), jnp.uint8),
+        "k_scales": jnp.full((n_pages, hkv, ps, nb), 127, jnp.uint8),
+        "v_codes": jnp.zeros((n_pages, hkv, ps, hd // 2), jnp.uint8),
+        "v_scales": jnp.full((n_pages, hkv, ps, nb), 127, jnp.uint8),
     }
     tables = jnp.arange(1, 1 + B * n_pp, dtype=jnp.int32).reshape(B, n_pp)
     lengths = jnp.full((B,), ps * n_pp, jnp.int32)

@@ -852,6 +852,9 @@ def main():
                     help="where to write the schema-versioned benchmark "
                          "baseline ('' to skip)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         args.reduced, args.requests, args.max_new, args.slots = True, 4, 4, 2
         args.shared_prefix = True

@@ -10,7 +10,9 @@ E8M0 (power-of-two) scale shared over 1-D blocks of 32 elements.
 All grids are represented explicitly as sorted jnp arrays so that RTN /
 stochastic rounding can be written once, generically, and verified against
 ``jnp.float4_e2m1fn`` casts (which JAX implements natively with
-round-to-nearest-ties-even semantics — see tests/test_formats.py).
+round-to-nearest-ties-even semantics — see tests/test_formats.py).  The
+library itself never uses that cast: :func:`rtn_e2m1` is arithmetic, the
+form that also lowers inside a TPU kernel.
 """
 
 from __future__ import annotations
@@ -224,22 +226,16 @@ def rtn_to_grid(x: jnp.ndarray, grid: jnp.ndarray) -> jnp.ndarray:
     return grid[idx]
 
 
-_HAS_NATIVE_E2M1 = hasattr(jnp, "float4_e2m1fn")
-
-
 def rtn_e2m1(x: jnp.ndarray) -> jnp.ndarray:
-    """Hardware-exact E2M1 RTN (ties-to-even, saturating).
-
-    Uses the native ``float4_e2m1fn`` cast when this JAX exposes it; otherwise
-    an arithmetic fallback with identical semantics: saturate to ±6, then
-    round the mantissa to 1 bit per binade with ``jnp.round`` (which is
-    round-half-to-even, matching IEEE).  Subnormals (|x| < 1) live on the
-    uniform {0, 0.5, 1} grid, so a single half-unit round covers them.  The
-    fallback is pure arithmetic (no gathers), so it also lowers inside Pallas
-    kernel bodies.
+    """Hardware-exact E2M1 RTN (ties-to-even, saturating), in arithmetic:
+    saturate to ±6, then round the mantissa to 1 bit per binade with
+    ``jnp.round`` (round-half-to-even, matching IEEE).  Subnormals (|x| < 1)
+    live on the uniform {0, 0.5, 1} grid, so a single half-unit round covers
+    them.  Same values as the native ``float4_e2m1fn`` cast
+    (tests/test_formats.py), but pure arithmetic with no gathers, so it
+    lowers inside Pallas kernel bodies on every TPU generation — Mosaic
+    refuses 4-bit float conversions before TPU v7.
     """
-    if _HAS_NATIVE_E2M1:
-        return x.astype(jnp.float4_e2m1fn).astype(jnp.float32)
     x = jnp.asarray(x, jnp.float32)
     a = jnp.clip(jnp.abs(x), 0.0, 6.0)
     # normals (1 <= a <= 6): a = m * 2^e with m in [1, 2), e in {0, 1, 2};
@@ -287,12 +283,14 @@ def stochastic_round_to_grid(
 _E2M1_POS_F32 = np.asarray(_E2M1_POS, dtype=np.float32)
 
 
-def e2m1_to_nibble(q: jnp.ndarray) -> jnp.ndarray:
-    """On-grid E2M1 values (scale 1) → 4-bit codes 0..15 (uint8).
+def e2m1_to_nibble_i32(q: jnp.ndarray) -> jnp.ndarray:
+    """On-grid E2M1 values (scale 1) → 4-bit codes 0..15 as int32.
 
     Pure arithmetic (no searchsorted): for |q| ≥ 1 the magnitude index is
     2 + 2·e + m with e = floor(log2|q|) and m the half-step mantissa bit;
     below 1 the grid is uniform at 0.5.  Negative zero maps to code 0.
+    int32 throughout, so kernel bodies can pack the codes before the one
+    int32 → uint8 narrowing at the store (Mosaic has no float → uint8 cast).
     """
     q = jnp.asarray(q, jnp.float32)
     a = jnp.abs(q)
@@ -300,8 +298,13 @@ def e2m1_to_nibble(q: jnp.ndarray) -> jnp.ndarray:
     m = a / exp2i(e) * 2.0 - 2.0  # 0 or 1 for on-grid normals
     idx_norm = 2.0 + 2.0 * e + m
     idx = jnp.where(a >= 1.0, idx_norm, a * 2.0)
-    sign = (q < 0).astype(jnp.uint8) << 3
-    return idx.astype(jnp.uint8) | sign
+    sign = (q < 0).astype(jnp.int32) << 3
+    return idx.astype(jnp.int32) | sign
+
+
+def e2m1_to_nibble(q: jnp.ndarray) -> jnp.ndarray:
+    """On-grid E2M1 values (scale 1) → 4-bit codes 0..15 (uint8)."""
+    return e2m1_to_nibble_i32(q).astype(jnp.uint8)
 
 
 def nibble_to_e2m1(codes: jnp.ndarray) -> jnp.ndarray:

@@ -223,8 +223,8 @@ def batch_partition(mesh: Mesh, batch: int, seq: int | None = None) -> P:
 def serve_pool_partition(pool: Any, mesh: Mesh) -> Any:
     """PartitionSpecs for the serving engine's packed page pool.
 
-    Every pool leaf is ``[L, n_pages, page, H, payload]`` (packed codes,
-    scales, or a dense-dtype payload) — the KV-head dim (axis 3) is the
+    Every pool leaf is ``[L, n_pages, H, page, payload]`` (packed codes,
+    scales, or a dense-dtype payload) — the KV-head dim (axis 2) is the
     natural shard axis for GQA serving: the paged-attention grid is already
     ``(B, Hkv, pages)``, so each shard runs the identical kernel over its
     local ``Hkv/tp`` heads and local pool slice.  Divisibility-guarded like
@@ -233,8 +233,8 @@ def serve_pool_partition(pool: Any, mesh: Mesh) -> Any:
     (consistent by construction)."""
 
     def spec(leaf):
-        ax = _fit(mesh, leaf.shape[3], "model")
-        return P(None, None, None, ax, None)
+        ax = _fit(mesh, leaf.shape[2], "model")
+        return P(None, None, ax, None, None)
 
     return jax.tree.map(spec, pool)
 

@@ -25,6 +25,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -87,7 +89,7 @@ def flash_attention(
     block_k: int = 256,
     q_heads: int = 1,
     kv_heads: int = 1,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """GQA-native: the KV row for query row ``bh`` is resolved in the block
     index map (``(bh // Hq)·Hkv + (bh % Hq) // group``), so KV heads are read
@@ -130,12 +132,12 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),  # running denom
             pltpu.VMEM((bq, hd), jnp.float32),  # running numerator
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)
     return out[:, :s, :]
 
 
-def mha_flash(q, k, v, causal: bool = True, interpret: bool = True,
+def mha_flash(q, k, v, causal: bool = True, interpret: bool | None = None,
               block_q: int = 256, block_k: int = 256):
     """[B, S, Hq, hd] × [B, T, Hkv, hd] (GQA) → [B, S, Hq, hd]."""
     B, S, Hq, hd = q.shape

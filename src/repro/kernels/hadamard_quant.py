@@ -7,8 +7,9 @@ pass fuses
      MXU matmul against the constant normalized Hadamard matrix,
   2. per-32-group RMSE-optimal (QuEST) scale computation,
   3. E8M0 (power-of-two) scale rounding,
-  4. E2M1 round-to-nearest downcast (the Blackwell PTX cvt → a native
-     float4_e2m1fn cast on TPU/interpret),
+  4. E2M1 round-to-nearest downcast (the Blackwell PTX cvt → the arithmetic
+     ties-to-even ``core.formats.rtn_e2m1``; Mosaic refuses 4-bit float
+     conversions before TPU v7),
   5. clip-mask generation for the backward trust estimator,
 
 writing half-codes (int8 = 2×grid value), scales, and masks back to HBM.
@@ -26,6 +27,7 @@ from jax.experimental import pallas as pl
 
 from repro.core import formats as F
 from repro.core.hadamard import hadamard_matrix
+from repro.kernels.backend import interpret_mode
 
 GROUP = 32
 _E2M1_MAX = 6.0
@@ -74,7 +76,7 @@ def hadamard_quest_quantize(
     x: jnp.ndarray,
     block_m: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """x: [M, K] → (codes int8 [M,K], scales f32 [M,K/32], mask bool [M,K])."""
     m, k = x.shape
@@ -109,7 +111,7 @@ def hadamard_quest_quantize(
             jax.ShapeDtypeStruct((grid_m * bm, k // GROUP), jnp.float32),
             jax.ShapeDtypeStruct((grid_m * bm, k), jnp.int8),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, hmat)
     if pad_m:
         codes, scales, mask = codes[:m], scales[:m], mask[:m]

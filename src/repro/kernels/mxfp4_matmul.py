@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import interpret_mode
+
 GROUP = 32
 
 
@@ -66,7 +68,7 @@ def mxfp4_matmul(
     block_m: int = 256,
     block_n: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """(A codes [M,K], scales [M,K/32]) × (B codes [K,N], scales [K/32,N]) → f32 [M,N]."""
     m, k = a_codes.shape
@@ -101,6 +103,6 @@ def mxfp4_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((gm * bm, gn * bn), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a_codes, a_scales, b_codes, b_scales)
     return out[:m, :n]

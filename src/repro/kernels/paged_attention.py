@@ -57,6 +57,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import formats as F
 from repro.core import quantizers as Q
+from repro.kernels.backend import interpret_mode
 from repro.kernels.kv_pack import unpack_dequant
 
 GROUP = 32
@@ -67,9 +68,12 @@ class PagedKV(NamedTuple):
     """Paged decode-attention state (a pytree, scannable over layers).
 
     ``pool``   — dict of pool leaves.  Packed mode: ``k_codes``/``v_codes``
-                 u8 [..., n_pages, ps, Hkv, hd/2] + ``k_scales``/``v_scales``
-                 u8 [..., n_pages, ps, Hkv, hd/block]; dense mode: ``k``/``v``
-                 in the compute dtype [..., n_pages, ps, Hkv, hd].
+                 u8 [..., n_pages, Hkv, ps, hd/2] + ``k_scales``/``v_scales``
+                 u8 [..., n_pages, Hkv, ps, hd/block]; dense mode: ``k``/``v``
+                 in the compute dtype [..., n_pages, Hkv, ps, hd].  The KV-head
+                 axis sits ahead of the token axis so one (page, head) block
+                 is a whole ``[ps, payload]`` tile — the last two dims of the
+                 array, which is what Mosaic requires of a block.
     ``tables`` — int32 [..., B, pages_per_slot] page table (masked lanes'
                  rows zeroed by the engine so their writes land on the
                  reserved scratch page 0).
@@ -100,19 +104,23 @@ def scatter_token(pool: dict, page_ids: jnp.ndarray, offsets: jnp.ndarray,
     ``[..., Hkv, hd]``.  Quantize-on-write in packed mode.  Duplicate
     (page, offset) pairs (masked lanes redirected to the scratch page)
     resolve arbitrarily — scratch contents are never read.
+
+    The index ``[page_ids, :, offsets]`` puts its broadcast ``[...]`` dims
+    first (the two index arrays are split by the head slice), so the
+    ``[..., Hkv, payload]`` values land as they come.
     """
     if "k" in pool:
         return {
-            "k": pool["k"].at[page_ids, offsets].set(k_new.astype(pool["k"].dtype)),
-            "v": pool["v"].at[page_ids, offsets].set(v_new.astype(pool["v"].dtype)),
+            "k": pool["k"].at[page_ids, :, offsets].set(k_new.astype(pool["k"].dtype)),
+            "v": pool["v"].at[page_ids, :, offsets].set(v_new.astype(pool["v"].dtype)),
         }
     fmt = quant_fmt(k_new.shape[-1])
     kq, vq = Q.kv_quantize(k_new, fmt), Q.kv_quantize(v_new, fmt)
     return {
-        "k_codes": pool["k_codes"].at[page_ids, offsets].set(kq.codes),
-        "k_scales": pool["k_scales"].at[page_ids, offsets].set(kq.scales),
-        "v_codes": pool["v_codes"].at[page_ids, offsets].set(vq.codes),
-        "v_scales": pool["v_scales"].at[page_ids, offsets].set(vq.scales),
+        "k_codes": pool["k_codes"].at[page_ids, :, offsets].set(kq.codes),
+        "k_scales": pool["k_scales"].at[page_ids, :, offsets].set(kq.scales),
+        "v_codes": pool["v_codes"].at[page_ids, :, offsets].set(vq.codes),
+        "v_scales": pool["v_scales"].at[page_ids, :, offsets].set(vq.scales),
     }
 
 
@@ -218,15 +226,14 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, *rest,
 def _load_kv_mxfp4(block: int):
     def load(kv_refs):
         kc, ks, vc, vs = kv_refs
-        return (unpack_dequant(kc[0, :, 0, :], ks[0, :, 0, :], block),
-                unpack_dequant(vc[0, :, 0, :], vs[0, :, 0, :], block))
+        return (unpack_dequant(kc[0, 0], ks[0, 0], block),
+                unpack_dequant(vc[0, 0], vs[0, 0], block))
     return load
 
 
 def _load_kv_dense(kv_refs):
     k_ref, v_ref = kv_refs
-    return (k_ref[0, :, 0, :].astype(jnp.float32),
-            v_ref[0, :, 0, :].astype(jnp.float32))
+    return k_ref[0, 0].astype(jnp.float32), v_ref[0, 0].astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -241,7 +248,7 @@ def paged_attention(
     tables: jnp.ndarray,  # [B, pages_per_slot] int32
     lengths: jnp.ndarray,  # [B] int32 — tokens visible to the FIRST query
     #                        (its position + 1); query s sees lengths + s
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Decode/verify attention directly over the paged pool.
 
@@ -255,7 +262,7 @@ def paged_attention(
     B, S, Hq, hd = q.shape
     quantized = "k_codes" in pool
     kleaf = pool["k_codes"] if quantized else pool["k"]
-    ps, Hkv = kleaf.shape[1], kleaf.shape[2]
+    Hkv, ps = kleaf.shape[1], kleaf.shape[2]
     group = Hq // Hkv
     n_pp = tables.shape[1]
     scale = 1.0 / np.sqrt(hd)
@@ -267,7 +274,7 @@ def paged_attention(
 
     def kv_idx(b, h, p, tbl, ln):
         del ln
-        return (tbl[b, p], 0, h, 0)
+        return (tbl[b, p], h, 0, 0)
 
     def q_idx(b, h, p, tbl, ln):
         del p, tbl, ln
@@ -277,18 +284,18 @@ def paged_attention(
         block = quant_block(hd)
         load_kv = _load_kv_mxfp4(block)
         kv_specs = [
-            pl.BlockSpec((1, ps, 1, hd // 2), kv_idx),
-            pl.BlockSpec((1, ps, 1, hd // block), kv_idx),
-            pl.BlockSpec((1, ps, 1, hd // 2), kv_idx),
-            pl.BlockSpec((1, ps, 1, hd // block), kv_idx),
+            pl.BlockSpec((1, 1, ps, hd // 2), kv_idx),
+            pl.BlockSpec((1, 1, ps, hd // block), kv_idx),
+            pl.BlockSpec((1, 1, ps, hd // 2), kv_idx),
+            pl.BlockSpec((1, 1, ps, hd // block), kv_idx),
         ]
         operands = (pool["k_codes"], pool["k_scales"],
                     pool["v_codes"], pool["v_scales"])
     else:
         load_kv = _load_kv_dense
         kv_specs = [
-            pl.BlockSpec((1, ps, 1, hd), kv_idx),
-            pl.BlockSpec((1, ps, 1, hd), kv_idx),
+            pl.BlockSpec((1, 1, ps, hd), kv_idx),
+            pl.BlockSpec((1, 1, ps, hd), kv_idx),
         ]
         operands = (pool["k"], pool["v"])
     rows = S * group
@@ -310,7 +317,7 @@ def paged_attention(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(tables, lengths, qg, *operands)
     out = (out.reshape(B, Hkv, S, group, hd)
            .transpose(0, 2, 1, 3, 4)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.hadamard import hadamard_matrix
+from repro.kernels.backend import interpret_mode
 
 GROUP = 32
 _E2M1_MAX = 6.0
@@ -73,7 +74,7 @@ def sr_hadamard_quantize(
     prescale: float = 0.75,
     block_m: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """x: [M, K], signs: [K] ±1, u: [M, K] uniforms →
     (codes int8 [M, K], scales f32 [M, K/32])."""
@@ -108,7 +109,7 @@ def sr_hadamard_quantize(
             jax.ShapeDtypeStruct((grid_m * bm, k), jnp.int8),
             jax.ShapeDtypeStruct((grid_m * bm, k // GROUP), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, signs, u, jnp.asarray(hadamard_matrix(GROUP), jnp.float32))
     if pad_m:
         codes, scales = codes[:m], scales[:m]

@@ -8,16 +8,11 @@ multi-pod = 2×16×16 = 512 chips with a leading "pod" axis (DCI-connected).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
 
-    def _mk(shape, axes):
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-except ImportError:  # jax 0.4.x: no AxisType / axis_types kwarg — plain mesh
-    def _mk(shape, axes):
-        return jax.make_mesh(shape, axes)
+def _mk(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.configs import get_config, get_reduced_config
 from repro.distributed.context import activate_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.serve import (Engine, EngineConfig, SamplingParams, SpecConfig,
@@ -141,6 +142,7 @@ def main():
                          "spans, request lifecycles, jit-compile events) "
                          "written here — open in Perfetto/chrome://tracing")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_reduced_config(args.arch) if args.reduced else get_config(args.arch))
     model = build_model(cfg)

@@ -199,14 +199,14 @@ def _paged_decode(params, x, q, positions, seed, cfg: ModelConfig,
         # slots sit at genuinely different offsets — never share row 0's angles
         k = L.apply_rope(k, positions, cfg.rope_theta, shared=False)
 
-    kleaf = next(iter(paged.pool.values()))
-    ps = kleaf.shape[1]
+    kleaf = next(iter(paged.pool.values()))  # [n_pages, Hkv, ps, payload]
+    local_heads, ps = kleaf.shape[1], kleaf.shape[2]
     # tp: a head-sharded pool slice announces itself by shape — each shard
     # quantize-scatters and attends over its local Hkv/tp heads only, then
     # all_gathers the group outputs (exact concat) before the wo projection
-    tp_sharded = cfg.tp_axis is not None and kleaf.shape[2] != nkv
+    tp_sharded = cfg.tp_axis is not None and local_heads != nkv
     if tp_sharded:
-        q, k, v = _tp_slice_heads(q, k, v, cfg, kleaf.shape[2])
+        q, k, v = _tp_slice_heads(q, k, v, cfg, local_heads)
     B, S = x.shape[0], x.shape[1]
     bidx = jnp.arange(B)
     page_ids = paged.tables[bidx[:, None], positions // ps]  # [B, S]

@@ -33,24 +33,6 @@ from repro.models.attention import attention, init_attention
 LAYER_SEED_STRIDE = 2654435761  # Knuth multiplicative hash increment
 
 
-@jax.custom_vjp
-def _barrier(x):
-    """optimization_barrier with a differentiation rule (jax 0.4.x has none):
-    identity value/gradient, barrier on both passes."""
-    return jax.lax.optimization_barrier(x)
-
-
-def _barrier_fwd(x):
-    return _barrier(x), None
-
-
-def _barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-_barrier.defvjp(_barrier_fwd, _barrier_bwd)
-
-
 def init_mlp(key, cfg: ModelConfig, dtype):
     d, f = cfg.d_model, cfg.d_ff
     ks = jax.random.split(key, 3)
@@ -161,7 +143,7 @@ def _layer_scan(params_layers, x, positions, seed, cfg, caches, cache_index,
         layer_params = constrain_layer_params(layer_params)
         # barrier: stops XLA hoisting the carry's bf16→f32 convert out of the
         # backward while as a whole-stack [L, B, S, D] f32 loop invariant
-        x = _barrier(x)
+        x = jax.lax.optimization_barrier(x)
         seed_l = (seed + layer_idx.astype(jnp.uint32) * jnp.uint32(LAYER_SEED_STRIDE)).astype(jnp.uint32)
         x, new_cache, aux_l = block_apply(layer_params, x, positions, seed_l, cfg,
                                           cache, cache_index, method, **block_kw)

@@ -229,10 +229,11 @@ class Engine:
                 model, n_slots=cfg.n_slots, pages_per_slot=pages_per_slot,
                 page_size=cfg.page_size, n_pages=n_pages, kv_dtype=cfg.kv_dtype,
                 debug=cfg.debug_cache)
-            if placement.tp > 1:
+            if placement.mesh is not None:
                 # pool shards on the KV-head axis over the placement mesh;
                 # params replicate (serving TP = KV/attention/expert
-                # parallelism, not weight sharding — see serve/README.md)
+                # parallelism, not weight sharding — see serve/README.md).
+                # A one-device mesh (DP replica) pins both to its device.
                 self.cache.pool = placement.shard_pool(self.cache.pool)
                 self.params = placement.replicate(self.params)
             self.decode_backend = cfg.decode_backend or (
@@ -252,11 +253,10 @@ class Engine:
                 model, n_slots=cfg.n_slots, max_len=cfg.max_len,
                 page_size=cfg.page_size, kv_dtype=cfg.kv_dtype,
                 debug=cfg.debug_cache)
-            if placement.tp > 1:
+            if placement.mesh is not None:
                 # both paged planes shard on the KV-head axis (same mesh
                 # contract as the dense/moe pool); params replicate
-                self.cache.kv.pool = placement.shard_pool(self.cache.kv.pool)
-                self.cache.cross.pool = placement.shard_pool(self.cache.cross.pool)
+                self.cache.set_pools(placement.shard_pool(self.cache.pools()))
                 self.params = placement.replicate(self.params)
             self.decode_backend = "statepool"
             self._steps = build_state_steps(

@@ -3,7 +3,7 @@
 Two cache backends behind one interface:
 
 * :class:`PagedCache` — attention-KV families (``dense``/``moe``): a global
-  pool of fixed-size pages ``[L, n_pages, page_size, Hkv, hd]`` with a host-
+  pool of fixed-size pages ``[L, n_pages, Hkv, page_size, hd]`` with a host-
   side free-list allocator and per-slot page tables.  In ``kv_dtype="mxfp4"``
   mode pages hold the *real* 4.25-bit payload (packed E2M1 nibble codes +
   E8M0 scale-exponent bytes, via ``core.quantizers.kv_quantize``); the
@@ -74,11 +74,9 @@ def gather_pages(pool: dict, tables: jnp.ndarray, dtype) -> tuple[jnp.ndarray, j
     """
 
     def one(codes, scales=None):
-        g = codes[:, tables]  # [L, B, np, ps, H, hd?]
-        if scales is None:
-            return g.reshape(*g.shape[:2], -1, *g.shape[4:])
-        s = scales[:, tables]
-        vals = dequantize_kv(g, s, dtype)
+        g = codes[:, tables]  # [L, B, np, H, ps, payload]
+        vals = g if scales is None else dequantize_kv(g, scales[:, tables], dtype)
+        vals = jnp.swapaxes(vals, 3, 4)  # [L, B, np, ps, H, hd]
         return vals.reshape(*vals.shape[:2], -1, *vals.shape[4:])
 
     if "k" in pool:  # dense mode
@@ -94,20 +92,22 @@ def scatter_tokens(pool: dict, page_ids: jnp.ndarray, offsets: jnp.ndarray,
     page_ids/offsets [N]; k_new/v_new [L, N, Hkv, hd].  Quantize-on-write in
     packed mode.  Duplicate (page, offset) pairs (masked lanes redirected to
     the scratch page) resolve arbitrarily — scratch contents are never read.
+    The index ``[:, page_ids, :, offsets]`` yields ``[N, L, Hkv, payload]``
+    (split index arrays broadcast first), hence the swap of the values.
     """
+
+    def put(leaf, vals):
+        return leaf.at[:, page_ids, :, offsets].set(
+            jnp.swapaxes(vals, 0, 1).astype(leaf.dtype))
+
     if "k" in pool:
-        k_store = k_new.astype(pool["k"].dtype)
-        v_store = v_new.astype(pool["v"].dtype)
-        return {
-            "k": pool["k"].at[:, page_ids, offsets].set(k_store),
-            "v": pool["v"].at[:, page_ids, offsets].set(v_store),
-        }
+        return {"k": put(pool["k"], k_new), "v": put(pool["v"], v_new)}
     kq, vq = quantize_kv(k_new), quantize_kv(v_new)
     return {
-        "k_codes": pool["k_codes"].at[:, page_ids, offsets].set(kq.codes),
-        "k_scales": pool["k_scales"].at[:, page_ids, offsets].set(kq.scales),
-        "v_codes": pool["v_codes"].at[:, page_ids, offsets].set(vq.codes),
-        "v_scales": pool["v_scales"].at[:, page_ids, offsets].set(vq.scales),
+        "k_codes": put(pool["k_codes"], kq.codes),
+        "k_scales": put(pool["k_scales"], kq.scales),
+        "v_codes": put(pool["v_codes"], vq.codes),
+        "v_scales": put(pool["v_scales"], vq.scales),
     }
 
 
@@ -171,7 +171,7 @@ class PagedCache:
                 raise ValueError(
                     f"PagedCache supports attention-KV families, got {cfg.family!r} "
                     f"(non-attention families carve planes via explicit geometry=)")
-            spec_k, _ = model.cache_spec(1, page_size)  # [L, 1, ps, Hkv, hd]
+            spec_k, _ = model.cache_spec(1, page_size)  # [L, 1, T, Hkv, hd]
             L, _, _, H, hd = spec_k.shape
             dtype = cfg.dtype
         else:
@@ -191,12 +191,12 @@ class PagedCache:
         self._dtype = jnp.dtype(dtype)
         nb = hd // _quant_fmt(hd).block
         if kv_dtype == "dense":
-            shape = (L, n_pages, page_size, H, hd)
+            shape = (L, n_pages, H, page_size, hd)
             self.pool = {"k": jnp.zeros(shape, self._dtype),
                          "v": jnp.zeros(shape, self._dtype)}
         else:
-            cshape = (L, n_pages, page_size, H, hd // 2)
-            sshape = (L, n_pages, page_size, H, nb)
+            cshape = (L, n_pages, H, page_size, hd // 2)
+            sshape = (L, n_pages, H, page_size, nb)
             self.pool = {"k_codes": jnp.zeros(cshape, jnp.uint8),
                          "k_scales": jnp.zeros(sshape, jnp.uint8),
                          "v_codes": jnp.zeros(cshape, jnp.uint8),

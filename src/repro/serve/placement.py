@@ -49,8 +49,10 @@ class ShardingConfig:
 class Placement:
     """One engine replica's device placement: a ``('model',)`` mesh of ``tp``
     devices plus helpers to put the pool (head-sharded) and everything else
-    (replicated) onto it.  ``tp == 1`` is the no-op placement — no mesh is
-    ever built, so single-device engines never touch device state here."""
+    (replicated) onto it.  ``tp == 1`` without a mesh is the no-op placement
+    — single-device engines then never touch device state here; ``tp == 1``
+    WITH a one-device mesh (a data-parallel replica) pins the replica's pool
+    and params to that mesh's device, so its jitted steps run there."""
 
     AXIS = "model"
 
@@ -58,13 +60,12 @@ class Placement:
         if tp < 1:
             raise ValueError(f"tp must be >= 1, got {tp}")
         self.tp = tp
-        if tp == 1:
-            self.mesh = None
-        else:
-            self.mesh = mesh if mesh is not None else make_serve_meshes(tp, 1)[0]
-            if self.mesh.size != tp:
-                raise ValueError(
-                    f"placement mesh has {self.mesh.size} devices, want tp={tp}")
+        if tp > 1 and mesh is None:
+            mesh = make_serve_meshes(tp, 1)[0]
+        if mesh is not None and mesh.size != tp:
+            raise ValueError(
+                f"placement mesh has {mesh.size} devices, want tp={tp}")
+        self.mesh = mesh
 
     def pool_specs(self, pool):
         """Head-axis PartitionSpecs for a pool pytree (replicated if tp==1)."""
@@ -73,7 +74,7 @@ class Placement:
         return serve_pool_partition(pool, self.mesh)
 
     def shard_pool(self, pool):
-        if self.tp == 1:
+        if self.mesh is None:
             return pool
         specs = self.pool_specs(pool)
         return jax.tree.map(
@@ -82,7 +83,7 @@ class Placement:
 
     def replicate(self, tree):
         """Replicate a pytree (params, tables, dense caches) over the mesh."""
-        if self.tp == 1:
+        if self.mesh is None:
             return tree
         return jax.tree.map(
             lambda l: jax.device_put(

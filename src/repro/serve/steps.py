@@ -452,7 +452,7 @@ def build_state_steps(model: Model, *, method: str, pool,
             if tp > 1:
                 # the plane shard holds Hkv/tp local heads; the projection
                 # above computed all of them — keep this shard's slice
-                local = next(iter(cross_pool.values())).shape[3]
+                local = next(iter(cross_pool.values())).shape[2]
                 if local != ks.shape[3]:
                     r = jax.lax.axis_index(type(placement).AXIS)
                     ks = jax.lax.dynamic_slice_in_dim(ks, r * local, local, axis=3)

@@ -46,7 +46,7 @@ def page_mask_from_tables(tables: np.ndarray, n_pages: int) -> np.ndarray:
 
 def _stream_health(codes: jnp.ndarray, scales: jnp.ndarray,
                    page_mask: jnp.ndarray) -> dict:
-    """One packed stream ([L, P, ps, H, hd/2] codes + [L, P, ps, H, nb]
+    """One packed stream ([L, P, H, ps, hd/2] codes + [L, P, H, ps, nb]
     scale codes) → masked clip/zero fractions and the scale histogram."""
     w = page_mask.astype(jnp.int32)[None, :, None, None, None]
     nib = split_nibbles(codes)  # [..., hd] u8

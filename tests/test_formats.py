@@ -30,6 +30,19 @@ def test_rtn_matches_native_fp4_cast():
     np.testing.assert_array_equal(ours[off_tie], native[off_tie])
 
 
+def test_rtn_e2m1_matches_native_fp4_cast_including_ties():
+    """The library's one E2M1 path is arithmetic; it must equal the native
+    float4_e2m1fn cast everywhere, ties (to even) and saturation included."""
+    x = np.concatenate([np.linspace(-8, 8, 6401),
+                        [0.25, 0.75, 1.25, 1.75, 2.5, 3.5, 5.0, 7.0, 1e9],
+                        [-0.25, -0.75, -1.25, -1.75, -2.5, -3.5, -5.0, -7.0, -1e9]])
+    x = x.astype(np.float32)
+    ours = np.asarray(F.rtn_e2m1(jnp.asarray(x)))
+    native = np.asarray(jnp.asarray(np.clip(x, -6, 6)).astype(jnp.float4_e2m1fn)
+                        .astype(jnp.float32))
+    np.testing.assert_array_equal(ours, native)
+
+
 def test_exp2i_exact():
     e = jnp.arange(-126, 128)
     got = np.asarray(F.exp2i(e), np.float64)

@@ -34,14 +34,14 @@ KEY = jax.random.PRNGKey(0)
 
 def _empty_pool(mode: str, n_pages: int, ps: int, Hkv: int, hd: int) -> dict:
     if mode == "dense":
-        shape = (n_pages, ps, Hkv, hd)
+        shape = (n_pages, Hkv, ps, hd)
         return {"k": jnp.zeros(shape, jnp.float32),
                 "v": jnp.zeros(shape, jnp.float32)}
     nb = hd // PA.quant_block(hd)
-    return {"k_codes": jnp.zeros((n_pages, ps, Hkv, hd // 2), jnp.uint8),
-            "k_scales": jnp.zeros((n_pages, ps, Hkv, nb), jnp.uint8),
-            "v_codes": jnp.zeros((n_pages, ps, Hkv, hd // 2), jnp.uint8),
-            "v_scales": jnp.zeros((n_pages, ps, Hkv, nb), jnp.uint8)}
+    return {"k_codes": jnp.zeros((n_pages, Hkv, ps, hd // 2), jnp.uint8),
+            "k_scales": jnp.zeros((n_pages, Hkv, ps, nb), jnp.uint8),
+            "v_codes": jnp.zeros((n_pages, Hkv, ps, hd // 2), jnp.uint8),
+            "v_scales": jnp.zeros((n_pages, Hkv, ps, nb), jnp.uint8)}
 
 
 def _paged_setup(mode, lengths, ps, Hkv, hd, pages_per_slot, seed=0):

@@ -114,15 +114,15 @@ def test_pool_sharded_on_head_axis():
         sharding=ShardingConfig(tp=2)))
     H = cfg.num_kv_heads
     for name, leaf in eng.cache.pool.items():
-        assert leaf.shape[3] == H
+        assert leaf.shape[2] == H
         shards = leaf.addressable_shards
         assert len(shards) == 2, name
         # each shard holds exactly its H/2-head slice — together they
         # conserve the full pool (no replication, no overlap)
         for s in shards:
-            assert s.data.shape[3] == H // 2, name
-        lo = sorted(shards, key=lambda s: s.index[3].start or 0)
-        full = np.concatenate([np.asarray(s.data) for s in lo], axis=3)
+            assert s.data.shape[2] == H // 2, name
+        lo = sorted(shards, key=lambda s: s.index[2].start or 0)
+        full = np.concatenate([np.asarray(s.data) for s in lo], axis=2)
         np.testing.assert_array_equal(full, np.asarray(leaf))
 
 
@@ -135,8 +135,8 @@ def test_sharded_pool_survives_workload_invariants():
     eng.cache.check_invariants()
     leaf = next(iter(eng.cache.pool.values()))
     assert len(leaf.addressable_shards) == 2
-    assert {s.data.shape[3] for s in leaf.addressable_shards} == {
-        leaf.shape[3] // 2}
+    assert {s.data.shape[2] for s in leaf.addressable_shards} == {
+        leaf.shape[2] // 2}
 
 
 # ---------------------------------------------------------------------------
@@ -219,7 +219,7 @@ def test_engine_rejects_dp_and_nonpaged_tp():
                EngineConfig(sharding=ShardingConfig(tp=1, dp=2)))
     ssm_cfg = get_reduced_config("falcon-mamba-7b")
     ssm = build_model(ssm_cfg)
-    with pytest.raises(ValueError, match="paged family"):
+    with pytest.raises(ValueError, match="paged families"):
         Engine(ssm, ssm.init(jax.random.PRNGKey(0)),
                EngineConfig(sharding=ShardingConfig(tp=2)))
 
