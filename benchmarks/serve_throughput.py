@@ -67,6 +67,7 @@ validated before writing, so the perf trajectory is tracked across PRs.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import pathlib
 import sys
@@ -425,13 +426,15 @@ def bench(arch: str = "qwen3-1.7b", reduced: bool = True, n_requests: int = 8,
         # the primary (mxfp4/paged) configuration streams its registry
         # snapshots and samples pool quantization health every tick; the
         # others keep the in-memory registry only (NullSink).  --profile-out
-        # additionally records the primary run's Chrome trace (the per-call
-        # cost lowering happens during warmup, outside the timed region)
+        # additionally records the primary run as a JAX profiler trace (the
+        # per-call cost lowering happens during warmup, outside the timed
+        # region)
+        traced = primary and profile_out is not None
         tcfg = TelemetryConfig(
             metrics_path=metrics_out if primary else None,
             emit_every_ticks=5 if primary and metrics_out else 0,
             quant_stride=1 if primary else 0,
-            profile_trace_path=profile_out if primary else None)
+            profile=traced)
         eng = Engine(model, params, EngineConfig(
             n_slots=n_slots, max_len=64, page_size=16, kv_dtype=kv,
             prefill_chunk=16, decode_backend=backend, spec=spec,
@@ -443,8 +446,11 @@ def bench(arch: str = "qwen3-1.7b", reduced: bool = True, n_requests: int = 8,
         eng.completed.clear()
         eng.telemetry.reset(eng)
 
+        trace = (jax.profiler.trace(profile_out, create_perfetto_trace=True)
+                 if traced else contextlib.nullcontext())
         t0 = time.perf_counter()
-        done, _ = run_workload(eng, workload, verbose=False)
+        with trace:
+            done, _ = run_workload(eng, workload, verbose=False)
         wall = time.perf_counter() - t0
         toks = sum(len(r.tokens) for r in done)
         agg = aggregate_stats(done)
@@ -843,11 +849,11 @@ def main():
                     help="stream the primary run's registry snapshots as "
                          "JSON-lines to this path (smoke default: "
                          "benchmarks/out/metrics_serve.jsonl)")
-    ap.add_argument("--profile-out", default=None,
-                    help="write the primary run's Chrome trace-event JSON "
-                         "(open in Perfetto / chrome://tracing) to this "
-                         "path (smoke default: benchmarks/out/"
-                         "trace_serve.json)")
+    ap.add_argument("--profile-out", default=None, metavar="DIR",
+                    help="record the primary run as a JAX profiler trace "
+                         "under DIR (serve.* spans and device ops; open its "
+                         "perfetto_trace.json.gz in Perfetto; smoke "
+                         "default: benchmarks/out/profile)")
     ap.add_argument("--bench-out", default=str(BENCH_PATH),
                     help="where to write the schema-versioned benchmark "
                          "baseline ('' to skip)")
@@ -865,7 +871,7 @@ def main():
         if args.metrics_out is None:
             args.metrics_out = str(out_dir / "metrics_serve.jsonl")
         if args.profile_out is None:
-            args.profile_out = str(out_dir / "trace_serve.json")
+            args.profile_out = str(out_dir / "profile")
     rep = bench(args.arch, args.reduced, args.requests, args.max_new,
                 args.slots, verify_parity=not args.no_parity,
                 spec_k=args.spec_k, spec_proposer=args.spec_proposer,
@@ -906,14 +912,15 @@ def main():
         assert prof["decode"]["flops_per_call"] > 0
         assert prof["decode"]["hbm_bytes_per_call"] > 0
         assert prof["decode"]["roofline_util_mean"] > 0
-        # the Chrome trace must load structurally and carry tick-phase,
-        # request-lifecycle, and compile events
+        # the profiler trace must hold the engine's tick spans
         if args.profile_out:
-            from repro.serve.telemetry.profiling import validate_trace_file
-            tdoc = validate_trace_file(args.profile_out)
-            cats = {e.get("cat") for e in tdoc["traceEvents"]}
-            assert {"tick", "phase", "request"} <= cats, \
-                f"trace missing span categories: {cats}"
+            from jax.profiler import ProfileData
+            xspace = sorted(pathlib.Path(args.profile_out).glob("plugins/profile/*/*.xplane.pb"))
+            assert xspace, f"no profiler trace under {args.profile_out}"
+            names = {e.name for p in ProfileData.from_file(str(xspace[-1])).planes
+                     for line in p.lines for e in line.events}
+            assert {"serve.step", "serve.decode.dispatch"} <= names, \
+                "trace holds no serve.* spans"
         # the persisted baseline must round-trip its schema validator
         doc = validate_bench_file(args.bench_out)
         assert doc["spec"]["acceptance_rate"] is None or \

@@ -14,6 +14,7 @@ is checked token-for-token against sequential ``greedy_generate``.
 """
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -56,10 +57,11 @@ def main():
                     help="stream telemetry snapshots as JSON-lines here")
     ap.add_argument("--trace-out", default=None,
                     help="write per-request span traces as JSON-lines here")
-    ap.add_argument("--profile-out", default=None,
+    ap.add_argument("--profile-out", default=None, metavar="DIR",
                     help="profile the run: roofline/bandwidth gauges + a "
-                         "Chrome trace-event JSON written here (open in "
-                         "Perfetto / chrome://tracing)")
+                         "JAX profiler trace under DIR (serve.* spans and "
+                         "device ops; open its perfetto_trace.json.gz in "
+                         "Perfetto)")
     args = ap.parse_args()
 
     cfg = get_reduced_config(args.arch)
@@ -76,7 +78,7 @@ def main():
         prefill_chunk=8, prefix_cache=args.prefix_cache, spec=spec,
         telemetry=TelemetryConfig(metrics_path=args.metrics_out,
                                   trace_path=args.trace_out,
-                                  profile_trace_path=args.profile_out,
+                                  profile=args.profile_out is not None,
                                   quant_stride=4)))
 
     # mixed prompt lengths, arrivals staggered over the first steps
@@ -90,16 +92,19 @@ def main():
         prompts = [np.concatenate([system, p]) for p in prompts]
     arrive_at_step = sorted(int(rng.integers(0, 4)) for _ in range(args.requests))
 
+    trace = (jax.profiler.trace(args.profile_out, create_perfetto_trace=True)
+             if args.profile_out else contextlib.nullcontext())
     t0 = time.time()
     handles, next_req = [], 0
-    while next_req < len(prompts) or engine.sched.pending:
-        while next_req < len(prompts) and arrive_at_step[next_req] <= engine.steps:
-            handles.append(engine.submit(prompts[next_req], args.max_new,
-                                         extra=extra, arrival_time=float(engine.steps)))
-            next_req += 1
-        info = engine.step(now=float(engine.steps))
-        print(f"step {info['step']:3d}: queued={info['queued']} "
-              f"prefill={info['prefilling']} decode={info['decoding']}")
+    with trace:
+        while next_req < len(prompts) or engine.sched.pending:
+            while next_req < len(prompts) and arrive_at_step[next_req] <= engine.steps:
+                handles.append(engine.submit(prompts[next_req], args.max_new, extra=extra,
+                                             arrival_time=float(engine.steps)))
+                next_req += 1
+            info = engine.step(now=float(engine.steps))
+            print(f"step {info['step']:3d}: queued={info['queued']} "
+                  f"prefill={info['prefilling']} decode={info['decoding']}")
     dt = time.time() - t0
 
     total = sum(len(h.tokens) for h in handles)

@@ -16,13 +16,17 @@ proposer) and reports tokens-per-verify-call and draft acceptance;
 Observability: ``--metrics-out m.jsonl`` streams registry snapshots as
 JSON-lines, ``--trace-out t.jsonl`` writes one line per retired request
 (spans + derived TTFT/TPOT), ``--quant-stride N`` samples the MXFP4 pool's
-clip/scale health every N ticks, and the run ends with the telemetry
-summary table (see ``serve/README.md#observability``).
+clip/scale health every N ticks, ``--profile-out DIR`` records the run as a
+JAX profiler trace (the engine's ``serve.*`` spans beside the device ops, on
+one clock; ``DIR/plugins/profile/<run>/`` holds the ``.xplane.pb`` and a
+``perfetto_trace.json.gz``), and the run ends with the telemetry summary table
+(see ``serve/README.md#observability``).
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -136,11 +140,11 @@ def main():
     ap.add_argument("--quant-stride", type=int, default=0,
                     help="sample MXFP4 pool clip/scale health every N ticks "
                          "(0 = off)")
-    ap.add_argument("--profile-out", default=None,
+    ap.add_argument("--profile-out", default=None, metavar="DIR",
                     help="profile the run: per-phase roofline/bandwidth "
-                         "gauges + a Chrome trace-event JSON (tick-phase "
-                         "spans, request lifecycles, jit-compile events) "
-                         "written here — open in Perfetto/chrome://tracing")
+                         "gauges + a JAX profiler trace written under DIR "
+                         "(serve.* spans and device ops on one clock; open "
+                         "its perfetto_trace.json.gz in Perfetto)")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -167,8 +171,10 @@ def main():
     telemetry = TelemetryConfig(metrics_path=args.metrics_out,
                                 trace_path=args.trace_out,
                                 quant_stride=args.quant_stride,
-                                profile_trace_path=args.profile_out)
-    with activate_mesh(make_local_mesh()):
+                                profile=args.profile_out is not None)
+    trace = (jax.profiler.trace(args.profile_out, create_perfetto_trace=True)
+             if args.profile_out else contextlib.nullcontext())
+    with trace, activate_mesh(make_local_mesh()):
         engine = Engine(model, params, EngineConfig(
             n_slots=args.slots, max_len=args.max_len, page_size=args.page_size,
             kv_dtype=args.kv, prefill_chunk=args.prefill_chunk, method=args.method,

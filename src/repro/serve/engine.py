@@ -91,6 +91,7 @@ from repro.serve.state_pool import STATE_FAMILIES, StatePool, cross_key
 from repro.serve.steps import (build_paged_steps, build_state_steps,
                                jit_cache_size, marshal_prefill_batch)
 from repro.serve.telemetry import EngineTelemetry, TelemetryConfig
+from repro.serve.telemetry.tracing import span
 from repro.train.serve import make_chunk_prefill_step, make_decode_step
 
 PAGED_FAMILIES = ("dense", "moe")
@@ -207,6 +208,8 @@ class Engine:
         self.completed: list[Request] = []
         self._dtype = jnp.dtype(model.cfg.dtype)
         self.steps = 0
+        self.replica: int | None = None  # set by ReplicatedEngine; tags serve.step
+        self._tick: tuple[float, span] | None = None  # (now, serve.step) of a tick
 
         if self.paged:
             # sizing (table width vs pool pages) is the shared reservation-
@@ -321,13 +324,60 @@ class Engine:
     def step(self, now: float | None = None) -> dict:
         """One scheduler tick: admit → chunked prefill → batched decode (or
         draft/verify/accept with speculation on) → retire.  Returns a small
-        summary dict (counts) for driver loops."""
-        now = time.monotonic() if now is None else now
-        cfg = self.config
-        reg = self.telemetry.registry
-        t_tick = time.perf_counter()
+        summary dict (counts) for driver loops.
 
-        # -- admit ---------------------------------------------------------
+        Every section runs under a ``serve.*`` span (``telemetry.tracing``):
+        in the profiler's host plane when a trace runs, and in the
+        process-wide span ring always."""
+        now = time.monotonic() if now is None else now
+        sched = self.sched
+        tags = {} if self.replica is None else {"replica": self.replica}
+        with span("serve.step", step=self.steps + 1, queued=len(sched.queue),
+                  prefilling=len(sched.prefilling()),
+                  decoding=len(sched.decoding()), **tags) as tick:
+            self._tick = (now, tick)
+            with span("serve.admit") as sp:
+                admitted, blocked = self._admit(now)
+                sp.set(admitted=len(admitted), blocked_pages=int(blocked))
+
+            # -- chunked prefill: ALL prefilling paged slots in one jitted
+            #    call (gather oracle / dense slots: one per-slot call each)
+            if self._prefill_all is not None:
+                batch = sched.prefill_batch()
+                if batch:
+                    with span("serve.prefill") as sp:
+                        self._prefill_tick(batch, now)
+                    self.telemetry.phase("prefill", sp.seconds)
+            elif (reqs := sched.prefilling()):
+                with span("serve.prefill") as sp:
+                    for req in reqs:
+                        self._advance_prefill(req, now)
+                self.telemetry.phase("prefill", sp.seconds)
+
+            # -- one batched decode/verify over all decoding slots -----------
+            decoding = sched.decoding()
+            if decoding:
+                phase = "decode" if self.spec is None else "verify"
+                with span(f"serve.{phase}") as sp:
+                    if self.spec is None:
+                        self._decode_tick(decoding)
+                    else:
+                        self._spec_tick(decoding)
+                self.telemetry.phase(phase, sp.seconds)
+
+            self.steps += 1
+            with span("serve.telemetry"):
+                self.telemetry.end_tick(self, now, tick.seconds)
+        self._tick = None
+        return {"admitted": len(admitted), "prefilling": len(sched.prefilling()),
+                "decoding": len(sched.decoding()),
+                "queued": len(sched.queue), "step": self.steps}
+
+    def _admit(self, now: float) -> tuple[list[Request], bool]:
+        """Admit queued requests into free slots; returns them and whether
+        the queue head had a slot but no pages."""
+        reg = self.telemetry.registry
+
         def can_admit(req: Request) -> bool:
             if self.backend == "dense_slots":
                 return True
@@ -356,44 +406,30 @@ class Engine:
                 self.proposer.on_admit(req)
             self.telemetry.tracer.event(req.rid, "admit", now)
         reg.counter("requests_admitted").inc(len(admitted))
-        if self.sched.queue and self.sched.free_slots:
+        blocked = bool(self.sched.queue and self.sched.free_slots)
+        if blocked:
             # a slot is free but the FIFO head didn't fit: page pressure
             reg.counter("admission_blocked_pages").inc()
+        return admitted, blocked
 
-        # -- chunked prefill: ALL prefilling paged slots in one jitted call
-        #    (gather oracle / dense slots: one per-slot call each) ----------
-        t0 = time.perf_counter()
-        did_prefill = False
-        if self._prefill_all is not None:
-            batch = self.sched.prefill_batch()
-            if batch:
-                self._prefill_tick(batch, now)
-                did_prefill = True
-        else:
-            for req in self.sched.prefilling():
-                self._advance_prefill(req, now)
-                did_prefill = True
-        if did_prefill:
-            self.telemetry.phase("prefill", now, t_tick, t0, time.perf_counter())
+    def _emit_time(self) -> float:
+        """Engine-clock stamp for a token emitted now: the tick's ``now``
+        plus the time the tick has taken so far."""
+        now, tick = self._tick
+        return now + tick.seconds
 
-        # -- one batched decode/verify over all decoding slots ---------------
-        decoding = self.sched.decoding()
-        if decoding:
-            t0 = time.perf_counter()
-            if self.spec is not None:
-                self._spec_tick(decoding, now)
-                self.telemetry.phase("verify", now, t_tick, t0,
-                                     time.perf_counter())
-            else:
-                self._decode_tick(decoding, now)
-                self.telemetry.phase("decode", now, t_tick, t0,
-                                     time.perf_counter())
-
-        self.steps += 1
-        self.telemetry.end_tick(self, now, time.perf_counter() - t_tick)
-        return {"admitted": len(admitted), "prefilling": len(self.sched.prefilling()),
-                "decoding": len(self.sched.decoding()),
-                "queued": len(self.sched.queue), "step": self.steps}
+    def _fetch_logits(self, phase: str, logits) -> np.ndarray:
+        """Wait for the step's logits, copy them to the host as float32.  The
+        copy is queued first, so it starts when the step ends, as a plain
+        ``np.asarray`` would; ``wait`` then ends when the step's outputs are
+        ready and ``fetch`` holds what is left of the transfer."""
+        logits.copy_to_host_async()
+        with span(f"serve.{phase}.wait"):
+            jax.block_until_ready(logits)
+        with span(f"serve.{phase}.fetch", bytes=int(logits.nbytes)):
+            out = np.asarray(logits, np.float32)
+        self.telemetry.registry.counter("logits_bytes_fetched").inc(int(logits.nbytes))
+        return out
 
     def drain(self, max_steps: int = 100_000) -> list[Request]:
         """Step until every submitted request has finished."""
@@ -523,37 +559,42 @@ class Engine:
         self.telemetry.registry.counter("prefix_inserted_pages").inc(added)
 
     def _run_prefill_call(self, req: Request, tokens_np: np.ndarray):
-        start = jnp.int32(req.prefill_pos)
-        tokens = jnp.asarray(tokens_np[None, :], jnp.int32)
-        if self.paged:
-            self._cow_guard([(req.slot, req.prefill_pos, int(tokens_np.shape[0]))])
-            table_row = jnp.asarray(self.cache.tables[req.slot])
-            logits, self.cache.pool = self._prefill_chunk(
-                self.params, tokens, start, table_row, self.cache.pool, req.extra)
-        elif self.backend == "statepool":
-            sp = self.cache
-            kv_row = (jnp.asarray(sp.kv.tables[req.slot])
-                      if sp.kv is not None else None)
-            cross_row = (jnp.asarray(sp.cross.tables[req.slot])
-                         if sp.cross is not None else None)
-            read = np.array([sp.ring_read[req.slot]], np.int32)
-            write = np.array([sp.ring_write_id(req.slot)], np.int32)
-            logits, state = self._prefill_chunk(
-                self.params, tokens, start, sp.pools(), kv_row, cross_row,
-                jnp.asarray(read), jnp.asarray(write), req.extra)
-            sp.set_pools(state)
-            if sp.rings:
-                one = np.zeros((self.config.n_slots,), bool)
-                one[req.slot] = True
-                sp.ring_advance(one)
-        else:
-            logits, self.cache.caches = self._prefill_chunk(
-                self.params, tokens, start, jnp.int32(req.slot),
-                self.cache.caches, req.extra)
-        req.prefill_pos += tokens_np.shape[0]
-        self.telemetry.registry.counter("prefill_calls").inc()
-        self.telemetry.registry.counter("prompt_tokens_prefilled").inc(
-            int(tokens_np.shape[0]))
+        n = int(tokens_np.shape[0])
+        with span("serve.prefill.marshal"):
+            start = jnp.int32(req.prefill_pos)
+            tokens = jnp.asarray(tokens_np[None, :], jnp.int32)
+            if self.paged:
+                self._cow_guard([(req.slot, req.prefill_pos, n)])
+                rows = (jnp.asarray(self.cache.tables[req.slot]),)
+            elif self.backend == "statepool":
+                sp = self.cache
+                rows = (jnp.asarray(sp.kv.tables[req.slot]) if sp.kv is not None else None,
+                        jnp.asarray(sp.cross.tables[req.slot])
+                        if sp.cross is not None else None,
+                        jnp.asarray(np.array([sp.ring_read[req.slot]], np.int32)),
+                        jnp.asarray(np.array([sp.ring_write_id(req.slot)], np.int32)))
+        # a [1, n] call: every row it computes is a prompt token
+        with span("serve.prefill.dispatch", slots=1, rows_valid=n, rows_computed=n):
+            if self.paged:
+                logits, self.cache.pool = self._prefill_chunk(
+                    self.params, tokens, start, *rows, self.cache.pool, req.extra)
+            elif self.backend == "statepool":
+                logits, state = self._prefill_chunk(
+                    self.params, tokens, start, sp.pools(), *rows, req.extra)
+                sp.set_pools(state)
+                if sp.rings:
+                    one = np.zeros((self.config.n_slots,), bool)
+                    one[req.slot] = True
+                    sp.ring_advance(one)
+            else:
+                logits, self.cache.caches = self._prefill_chunk(
+                    self.params, tokens, start, jnp.int32(req.slot),
+                    self.cache.caches, req.extra)
+        req.prefill_pos += n
+        reg = self.telemetry.registry
+        reg.counter("prefill_calls").inc()
+        reg.counter("prompt_tokens_prefilled").inc(n)
+        reg.counter("prefill_rows_computed").inc(n)
         return logits
 
     def _prefill_tick(self, batch, now: float) -> None:
@@ -563,33 +604,41 @@ class Engine:
         sentinel column and returns each row's last-valid-token logits, from
         which slots that just consumed their whole prompt sample their first
         token."""
-        self._cow_guard([(req.slot, pos, n) for req, pos, n in batch])
-        tokens, start, n_valid, mask = marshal_prefill_batch(
-            self.config.n_slots, self.config.prefill_chunk,
-            ((req.slot, pos, req.prompt[pos:pos + n]) for req, pos, n in batch))
-        logits, self.cache.pool = self._prefill_all(
-            self.params, jnp.asarray(tokens), jnp.asarray(start),
-            jnp.asarray(n_valid), self.cache.pool,
-            jnp.asarray(self.cache.tables), jnp.asarray(mask))
+        with span("serve.prefill.marshal"):
+            self._cow_guard([(req.slot, pos, n) for req, pos, n in batch])
+            tokens, start, n_valid, mask = marshal_prefill_batch(
+                self.config.n_slots, self.config.prefill_chunk,
+                ((req.slot, pos, req.prompt[pos:pos + n]) for req, pos, n in batch))
+            args = (jnp.asarray(tokens), jnp.asarray(start), jnp.asarray(n_valid),
+                    self.cache.pool, jnp.asarray(self.cache.tables), jnp.asarray(mask))
+        valid = int(sum(n for _, _, n in batch))
+        with span("serve.prefill.dispatch", slots=len(batch), rows_valid=valid,
+                  rows_computed=int(tokens.size)):
+            logits, self.cache.pool = self._prefill_all(self.params, *args)
         reg = self.telemetry.registry
         reg.counter("prefill_calls").inc()
-        reg.counter("prompt_tokens_prefilled").inc(int(sum(n for _, _, n in batch)))
-        logits_np = None  # [B, V]; fetched only if some slot finished
+        reg.counter("prompt_tokens_prefilled").inc(valid)
+        reg.counter("prefill_rows_computed").inc(int(tokens.size))
+        finished = []
         for req, pos, n in batch:
             req.prefill_pos = pos + n
             if req.prefill_pos == req.prompt_len:
-                if logits_np is None:
-                    logits_np = np.asarray(logits, np.float32)
+                finished.append(req)
+        if not finished:
+            return  # [B, V] logits are fetched only if some slot finished
+        logits_np = self._fetch_logits("prefill", logits)
+        t = self._emit_time()
+        with span("serve.prefill.sample"):
+            for req in finished:
                 row = logits_np[req.slot]
                 tok = self._sample(req, row, 0)
                 if self.config.keep_logits:
                     req.logits_trace.append(row)
                 req.tokens.append(tok)
-                req.first_token_time = now
                 req.state = RequestState.DECODE
                 self._prefix_insert(req, req.prompt, now)
-                self._record_first_token(req, now)
-                self._maybe_finish(req, now)
+                self._record_first_token(req, t)
+                self._maybe_finish(req, t)
 
     def _advance_prefill(self, req: Request, now: float) -> None:
         """Per-slot prefill: the gather parity oracle and the dense-slot
@@ -606,70 +655,79 @@ class Engine:
                 logits = self._run_prefill_call(
                     req, req.prompt[req.prefill_pos:req.prefill_pos + 1])
         if req.prefill_pos == req.prompt_len:
-            logits_np = np.asarray(logits[0], np.float32)
-            tok = self._sample(req, logits_np, 0)
-            if self.config.keep_logits:
-                req.logits_trace.append(logits_np)
-            req.tokens.append(tok)
-            req.first_token_time = now
-            req.state = RequestState.DECODE
-            if self.paged:
-                self._prefix_insert(req, req.prompt, now)
-            self._record_first_token(req, now)
-            self._maybe_finish(req, now)
+            logits_np = self._fetch_logits("prefill", logits[0])
+            t = self._emit_time()
+            with span("serve.prefill.sample"):
+                tok = self._sample(req, logits_np, 0)
+                if self.config.keep_logits:
+                    req.logits_trace.append(logits_np)
+                req.tokens.append(tok)
+                req.state = RequestState.DECODE
+                if self.paged:
+                    self._prefix_insert(req, req.prompt, now)
+                self._record_first_token(req, t)
+                self._maybe_finish(req, t)
 
-    def _record_first_token(self, req: Request, now: float) -> None:
-        """Prefill just produced the request's first token: trace the span
-        boundary and count the emission (it rides on a prefill call, so it
-        counts toward ``tokens_generated`` but NOT ``decode_tokens``)."""
-        self.telemetry.tracer.event(req.rid, "first_token", now)
-        self.telemetry.tracer.tokens(req.rid, now, 1)
+    def _record_first_token(self, req: Request, t: float) -> None:
+        """Prefill just produced the request's first token at engine time
+        ``t``: stamp it, trace the span boundary and count the emission (it
+        rides on a prefill call, so it counts toward ``tokens_generated``
+        but NOT ``decode_tokens``)."""
+        req.first_token_time = t
+        self.telemetry.tracer.event(req.rid, "first_token", t)
+        self.telemetry.tracer.tokens(req.rid, t, 1)
         self.telemetry.registry.counter("tokens_generated").inc()
 
-    def _decode_tick(self, decoding: list[Request], now: float) -> None:
+    def _decode_tick(self, decoding: list[Request]) -> None:
         B = self.config.n_slots
-        tokens = np.zeros((B, 1), np.int32)
-        positions = np.zeros((B,), np.int32)
-        mask = np.zeros((B,), bool)
-        for req in decoding:
-            tokens[req.slot, 0] = req.tokens[-1]
-            positions[req.slot] = req.prompt_len + len(req.tokens) - 1
-            mask[req.slot] = True
-        args = (self.params, jnp.asarray(tokens), jnp.asarray(positions))
-        if self.paged:
-            self._cow_guard([(r.slot, int(positions[r.slot]), 1) for r in decoding])
-            logits, self.cache.pool = self._decode_all(
-                *args, self.cache.pool, jnp.asarray(self.cache.tables),
-                jnp.asarray(mask))
-        elif self.backend == "statepool":
-            sp = self.cache
-            ring_read, ring_write = sp.ring_ids(mask)
-            logits, state = self._decode_all(
-                *args, sp.pools(),
-                jnp.asarray(sp.kv.tables) if sp.kv is not None else None,
-                jnp.asarray(sp.cross.tables) if sp.cross is not None else None,
-                jnp.asarray(ring_read), jnp.asarray(ring_write),
-                jnp.asarray(mask))
-            sp.set_pools(state)
-            sp.ring_advance(mask)
-        else:
-            logits, self.cache.caches = self._decode_all(
-                *args, self.cache.caches, jnp.asarray(mask))
-        logits_np = np.asarray(logits, np.float32)
+        with span("serve.decode.marshal"):
+            tokens = np.zeros((B, 1), np.int32)
+            positions = np.zeros((B,), np.int32)
+            mask = np.zeros((B,), bool)
+            for req in decoding:
+                tokens[req.slot, 0] = req.tokens[-1]
+                positions[req.slot] = req.prompt_len + len(req.tokens) - 1
+                mask[req.slot] = True
+            args = (self.params, jnp.asarray(tokens), jnp.asarray(positions))
+            if self.paged:
+                self._cow_guard([(r.slot, int(positions[r.slot]), 1) for r in decoding])
+                args += (self.cache.pool, jnp.asarray(self.cache.tables))
+            elif self.backend == "statepool":
+                sp = self.cache
+                ring_read, ring_write = sp.ring_ids(mask)
+                args += (sp.pools(),
+                         jnp.asarray(sp.kv.tables) if sp.kv is not None else None,
+                         jnp.asarray(sp.cross.tables) if sp.cross is not None else None,
+                         jnp.asarray(ring_read), jnp.asarray(ring_write))
+            else:
+                args += (self.cache.caches,)
+            args += (jnp.asarray(mask),)
+        with span("serve.decode.dispatch", slots=len(decoding)):
+            if self.paged:
+                logits, self.cache.pool = self._decode_all(*args)
+            elif self.backend == "statepool":
+                logits, state = self._decode_all(*args)
+                sp.set_pools(state)
+                sp.ring_advance(mask)
+            else:
+                logits, self.cache.caches = self._decode_all(*args)
+        logits_np = self._fetch_logits("decode", logits)
+        t = self._emit_time()
         reg = self.telemetry.registry
         reg.counter("decode_calls").inc()
-        for req in decoding:
-            tok = self._sample(req, logits_np[req.slot], len(req.tokens))
-            if self.config.keep_logits:
-                req.logits_trace.append(logits_np[req.slot])
-            req.tokens.append(tok)
-            req.decode_calls += 1
-            self.telemetry.tracer.tokens(req.rid, now, 1)
-            self._maybe_finish(req, now)
+        with span("serve.decode.sample"):
+            for req in decoding:
+                tok = self._sample(req, logits_np[req.slot], len(req.tokens))
+                if self.config.keep_logits:
+                    req.logits_trace.append(logits_np[req.slot])
+                req.tokens.append(tok)
+                req.decode_calls += 1
+                self.telemetry.tracer.tokens(req.rid, t, 1)
+                self._maybe_finish(req, t)
         reg.counter("tokens_generated").inc(len(decoding))
         reg.counter("decode_tokens").inc(len(decoding))
 
-    def _spec_tick(self, decoding: list[Request], now: float) -> None:
+    def _spec_tick(self, decoding: list[Request]) -> None:
         """Draft → one batched verify → accept/rollback.
 
         Per slot with last accepted token t at position p0 and drafts
@@ -693,28 +751,39 @@ class Engine:
         slot's length and the rejected positions become unreachable (causal
         bounds + rewrite-before-read), with no page traffic.
         """
-        cfg, k = self.config, self.spec.k
-        B = cfg.n_slots
-        eos = cfg.eos_id
+        k, B = self.spec.k, self.config.n_slots
+        with span("serve.verify.draft"):
+            drafts = self.proposer.propose(decoding)  # [n_slots, k] int32
 
-        drafts = self.proposer.propose(decoding)  # [n_slots, k] int32
-
-        tokens = np.zeros((B, k + 1), np.int32)
-        start = np.zeros((B,), np.int32)
-        mask = np.zeros((B,), bool)
-        for req in decoding:
-            tokens[req.slot, 0] = req.tokens[-1]
-            tokens[req.slot, 1:] = drafts[req.slot]
-            start[req.slot] = req.prompt_len + len(req.tokens) - 1
-            mask[req.slot] = True
-        self._cow_guard([(r.slot, int(start[r.slot]), k + 1) for r in decoding])
-        logits, self.cache.pool = self._verify_all(
-            self.params, jnp.asarray(tokens), jnp.asarray(start),
-            self.cache.pool, jnp.asarray(self.cache.tables), jnp.asarray(mask))
-        logits_np = np.asarray(logits, np.float32)  # [B, k+1, V]
+        with span("serve.verify.marshal"):
+            tokens = np.zeros((B, k + 1), np.int32)
+            start = np.zeros((B,), np.int32)
+            mask = np.zeros((B,), bool)
+            for req in decoding:
+                tokens[req.slot, 0] = req.tokens[-1]
+                tokens[req.slot, 1:] = drafts[req.slot]
+                start[req.slot] = req.prompt_len + len(req.tokens) - 1
+                mask[req.slot] = True
+            self._cow_guard([(r.slot, int(start[r.slot]), k + 1) for r in decoding])
+            args = (jnp.asarray(tokens), jnp.asarray(start), self.cache.pool,
+                    jnp.asarray(self.cache.tables), jnp.asarray(mask))
+        with span("serve.verify.dispatch", slots=len(decoding)):
+            logits, self.cache.pool = self._verify_all(self.params, *args)
+        logits_np = self._fetch_logits("verify", logits)  # [B, k+1, V]
+        t = self._emit_time()
         reg = self.telemetry.registry
         reg.counter("verify_calls").inc()
+        with span("serve.verify.sample"):
+            self._accept_bursts(decoding, drafts, logits_np, t)
+        if (total := reg.counter("drafts_proposed").value):
+            reg.gauge("spec_acceptance_rate").set(
+                reg.counter("drafts_accepted").value / total)
 
+    def _accept_bursts(self, decoding, drafts, logits_np, t: float) -> None:
+        """Per slot: the target's draws over the k+1 rows, the accepted draft
+        prefix and the emitted burst, stamped at engine time ``t``."""
+        k, eos = self.spec.k, self.config.eos_id
+        reg = self.telemetry.registry
         for req in decoding:
             base = len(req.tokens)
             target = [self._sample(req, logits_np[req.slot, i], base + i)
@@ -748,17 +817,14 @@ class Engine:
             reg.counter("decode_tokens").inc(n_emit)
             reg.counter("drafts_proposed").inc(proposed)
             reg.counter("drafts_accepted").inc(min(n_acc, proposed))
-            self.telemetry.tracer.tokens(req.rid, now, n_emit)
-            self._maybe_finish(req, now)
+            self.telemetry.tracer.tokens(req.rid, t, n_emit)
+            self._maybe_finish(req, t)
             if not req.done:
                 # rollback is logical: the rejected suffix's positions are
                 # simply beyond the new length — pages stay mapped within the
                 # admission reservation and every position is rewritten
                 # before it is next read
                 self.proposer.on_accept(req)
-        if (total := reg.counter("drafts_proposed").value):
-            reg.gauge("spec_acceptance_rate").set(
-                reg.counter("drafts_accepted").value / total)
 
     def _maybe_finish(self, req: Request, now: float) -> None:
         eos = self.config.eos_id

@@ -23,11 +23,9 @@ cross-replica machinery is host-side:
   histograms POOL their sample reservoirs and cumulative buckets, so
   DP-aggregate TTFT/TPOT percentiles are computed over all replicas'
   samples (averaging per-replica percentiles would be statistically wrong);
-* **profiling** — with ``TelemetryConfig.profile_trace_path`` set, each
-  replica records its own trace lane (``pid`` = replica index) and
-  :meth:`ReplicatedEngine.write_profile` merges them into one
-  Perfetto-loadable document (per-replica engines get the path stripped so
-  they don't clobber each other's files).
+* **tracing** — each replica tags its ``serve.step`` span with
+  ``replica=<i>``, so one profiler trace (``jax.profiler.trace``) and the
+  process-wide span ring tell the replicas' ticks apart.
 
 Exactness: a request's tokens depend only on its own replica's engine, and
 every replica is token-exact vs a single-device engine (the TP contract), so
@@ -76,26 +74,16 @@ class ReplicatedEngine:
         tp, dp = sharding.tp, sharding.dp
         meshes = make_serve_meshes(tp, dp)
         ids = itertools.count()  # shared → globally-unique rids
-        # replicas get a dp-stripped config: each Engine validates tp only.
-        # A shared profile trace path is also stripped (replicas would
-        # clobber one file) — write_profile() merges the per-replica lanes.
+        # replicas get a dp-stripped config: each Engine validates tp only
         import dataclasses
         rep_cfg = dataclasses.replace(config, sharding=None)
-        self.profile_trace_path = None
-        tel = rep_cfg.telemetry
-        if tel is not None and tel.profile_trace_path:
-            self.profile_trace_path = tel.profile_trace_path
-            rep_cfg = dataclasses.replace(
-                rep_cfg, telemetry=dataclasses.replace(
-                    tel, profile_trace_path=None, profile=True))
         self.engines = [
             Engine(model, params, rep_cfg,
                    placement=Placement(tp, mesh=m), ids=ids)
             for m in meshes
         ]
         for r, e in enumerate(self.engines):
-            if e.telemetry.profiler is not None:
-                e.telemetry.profiler.pid = r
+            e.replica = r
         self.placer = ReplicaPlacer(dp)
         self.busy_s = [0.0] * dp
         self.sched = _SchedView(self.engines)
@@ -168,24 +156,6 @@ class ReplicatedEngine:
         agg = merged.snapshot()
         agg["replicas"] = len(self.engines)
         return agg
-
-    def write_profile(self, path: str | None = None) -> str | None:
-        """Finalize every replica's profiler (folding its completed request
-        traces in) and write ONE merged Chrome-trace document with a
-        process lane per replica.  Returns the path written, or ``None``
-        when profiling is off."""
-        from repro.serve.telemetry.profiling import write_trace
-        path = path or self.profile_trace_path
-        sinks = []
-        for e in self.engines:
-            prof = e.telemetry.profiler
-            if prof is not None:
-                prof.finalize(e.telemetry.tracer)
-                sinks.append(prof.sink)
-        if not sinks or path is None:
-            return None
-        write_trace(path, sinks)
-        return path
 
 
 def make_engine(model: Model, params, config: EngineConfig | None = None):

@@ -6,7 +6,9 @@ One :class:`EngineTelemetry` hub per engine wires together
   gauges / histograms / EWMA rates) pre-registered with the full metric
   catalog so the export schema is stable from tick 0,
 * a :class:`~repro.serve.telemetry.tracing.Tracer` deriving TTFT / TPOT /
-  queue-wait / latency from request-lifecycle spans,
+  queue-wait / latency from request-lifecycle spans (the engine's own
+  ``serve.*`` program spans live in the process-wide ring of
+  ``telemetry.tracing``, and feed the per-phase tick histograms here),
 * pluggable sinks (JSON-lines stream, Prometheus text exposition, console
   snapshots — see ``telemetry.sinks``),
 * quantization-health sampling of the packed MXFP4 pool at a configurable
@@ -70,7 +72,6 @@ class TelemetryConfig:
     keep_traces: int = 1024              # completed traces retained in memory
     hist_max_samples: int = 4096         # percentile reservoir size
     profile: bool = False                # per-phase cost accounting + roofline gauges
-    profile_trace_path: str | None = None  # Chrome trace-event JSON (implies profile)
 
 
 # name → (kind, help).  Pre-registered so every snapshot carries the full
@@ -89,6 +90,10 @@ CATALOG: dict[str, tuple[str, str]] = {
     "tokens_generated": ("counter", "tokens emitted (all phases)"),
     "decode_tokens": ("counter", "tokens emitted by decode/verify ticks"),
     "prompt_tokens_prefilled": ("counter", "prompt tokens consumed by prefill"),
+    "prefill_rows_computed": ("counter",
+                              "prefill rows the step programs computed, padding "
+                              "included (prompt_tokens_prefilled / this = fill)"),
+    "logits_bytes_fetched": ("counter", "logits bytes copied device -> host"),
     "prefill_calls": ("counter", "jitted prefill calls"),
     "decode_calls": ("counter", "jitted batched decode calls"),
     "cross_encode_calls": ("counter",
@@ -228,7 +233,7 @@ class EngineTelemetry:
 
     @property
     def profiling(self) -> bool:
-        return bool(self.cfg.profile or self.cfg.profile_trace_path)
+        return bool(self.cfg.profile)
 
     # -- engine lifecycle ---------------------------------------------------
 
@@ -264,32 +269,23 @@ class EngineTelemetry:
             for kind, s in stats.items():
                 g(f"pool_pages_total_{kind}").set(s["pages_total"])
                 g(f"pool_pages_free_{kind}").set(s["pages_free"])
-        # seed compile-count gauges so the profiler's compile-event diffing
-        # doesn't re-announce warmup compiles after a post-warmup reset
         for name, count in engine.compile_counts().items():
             g(f"jit_compiled_{name}").set(count)
         if self.profiling:
             from repro.serve.telemetry.profiling import EngineProfiler
             old = self.profiler
-            self.profiler = EngineProfiler(
-                engine, self.registry, trace_path=self.cfg.profile_trace_path,
-                pid=old.pid if old is not None else 0)
+            self.profiler = EngineProfiler(engine, self.registry)
             if old is not None and old.engine is engine:
-                # re-attach after reset(): drop the warmup trace but keep the
-                # memoized step costs (pure functions of the engine's avals)
+                # re-attach after reset(): keep the memoized step costs
+                # (pure functions of the engine's avals)
                 self.profiler._costs = old._costs
 
-    def phase(self, name: str, now: float, tick_t0: float,
-              t0: float, t1: float) -> None:
-        """One phase section of a tick finished.  ``tick_t0``/``t0``/``t1``
-        are ``perf_counter`` readings (tick entry / section start / section
-        end); ``now`` is the engine clock at tick entry — the profiler places
-        the span at ``now + (t0 - tick_t0)`` so traces and request spans
-        share one clock.  With profiling off this is exactly the histogram
-        observe the engine used to do inline."""
-        self.registry.histogram(f"{name}_tick_s").observe(t1 - t0)
+    def phase(self, name: str, seconds: float) -> None:
+        """One phase section of a tick finished, ``seconds`` long: the
+        duration of its ``serve.<name>`` span, the one timing source."""
+        self.registry.histogram(f"{name}_tick_s").observe(seconds)
         if self.profiler is not None:
-            self.profiler.on_phase(name, now + (t0 - tick_t0), t1 - t0)
+            self.profiler.on_phase(name, seconds)
 
     def end_tick(self, engine, now: float, wall_s: float) -> None:
         reg = self.registry
@@ -333,12 +329,7 @@ class EngineTelemetry:
                     g("prefix_hit_rate").set(
                         reg.counter("prefix_hit_requests").value / lookups)
         for name, count in engine.compile_counts().items():
-            gauge = g(f"jit_compiled_{name}")
-            if self.profiler is not None and count > gauge.value:
-                self.profiler.compile_event(name, now, count)
-            gauge.set(count)
-        if self.profiler is not None:
-            self.profiler.on_tick(engine, now, wall_s)
+            g(f"jit_compiled_{name}").set(count)
         toks = reg.counter("tokens_generated").value
         reg.rate("tokens_per_sec_ewma").mark(toks - self._last_tokens,
                                              time.perf_counter())
@@ -413,8 +404,6 @@ class EngineTelemetry:
         snap = self.emit(t)
         for sink in self.sinks:
             sink.close()
-        if self.profiler is not None:
-            self.profiler.finalize(self.tracer)
         self.tracer.close()
         self._finalized = True
         return snap

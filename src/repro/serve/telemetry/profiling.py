@@ -1,10 +1,12 @@
-"""Engine performance profiler: device cost accounting + Chrome-trace export.
+"""Engine performance profiler: per-phase device cost accounting.
 
-Two halves, both opt-in via :class:`~repro.serve.telemetry.TelemetryConfig`
-(``profile=True`` / ``profile_trace_path=...``) and both **host-side**: the
-profiler never changes what the engine's step functions compile or compute
-(the zero-interference contract extends to it — pinned by
-``tests/test_profiler.py``).
+Opt-in via :class:`~repro.serve.telemetry.TelemetryConfig` (``profile=True``)
+and **host-side**: the profiler never changes what the engine's step
+functions compile or compute (the zero-interference contract extends to it —
+pinned by ``tests/test_profiler.py``).  The timeline of a run is the JAX
+profiler's own trace: the engine's ``serve.*`` spans
+(:mod:`repro.serve.telemetry.tracing`) land in its host plane beside the
+device ops, on one clock (``--profile-out DIR`` on the front ends).
 
 **Per-phase device cost accounting** (:class:`EngineProfiler`).  Each jitted
 step the engine owns — batched decode, batched paged prefill, per-slot chunk
@@ -31,23 +33,11 @@ HLO; per-call costs are exact on real backends and a floor here (see
 ``serve/README.md#observability``).  Utilization gauges divide by the v5e
 constants from ``launch.roofline`` unless overridden — on CPU they are
 relative numbers for A/B deltas, not absolute hardware truth.
-
-**Chrome-trace export** (:class:`TraceEventSink`).  Engine ticks and their
-phase sections, request lifecycles (``queued → prefill → decode`` spans from
-the existing :class:`~repro.serve.telemetry.tracing.Tracer`), and
-jit-compile events are rendered as Chrome trace-event JSON —
-``chrome://tracing`` / Perfetto's legacy JSON format — on ONE shared clock:
-the engine's ``now`` (virtual or wall), with intra-tick phase offsets taken
-from the same ``perf_counter`` deltas that advance the virtual clock.
-Replicas map to trace *processes* (``pid`` = replica index), so a
-data-parallel engine renders as parallel lanes; within a process, lane 0 is
-the tick/phase timeline and each request gets its own named thread lane.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import json
 
 import jax
 import numpy as np
@@ -60,10 +50,6 @@ from repro.launch.roofline import HBM_BW, PEAK_FLOPS_BF16, analyze_compiled
 PHASES = ("prefill", "decode", "verify")
 _PHASE_COUNTERS = {"prefill": "prefill_calls", "decode": "decode_calls",
                    "verify": "verify_calls"}
-
-# trace lanes (tid) inside one engine process (pid)
-TID_ENGINE = 0
-TID_REQ_BASE = 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,97 +153,29 @@ def _phase_step(engine, phase: str) -> str | None:
     return None
 
 
-class TraceEventSink:
-    """Accumulates Chrome trace-event JSON objects for one engine process.
-
-    Complete (``ph: "X"``) events carry microsecond ``ts``/``dur`` on the
-    engine's clock; instant (``ph: "i"``) events mark point occurrences like
-    jit compiles.  ``write_trace`` merges any number of sinks (one per
-    replica) into one Perfetto-loadable document.
-    """
-
-    def __init__(self, pid: int = 0, process_name: str = "engine"):
-        self.pid = pid
-        self.process_name = process_name
-        self._events: list[dict] = []
-        self._thread_names: dict[int, str] = {TID_ENGINE: "engine ticks"}
-
-    def complete(self, name: str, cat: str, ts_s: float, dur_s: float,
-                 tid: int = TID_ENGINE, args: dict | None = None) -> None:
-        ev = {"name": name, "cat": cat, "ph": "X", "pid": self.pid, "tid": tid,
-              "ts": round(ts_s * 1e6, 3), "dur": round(max(dur_s, 0.0) * 1e6, 3)}
-        if args:
-            ev["args"] = args
-        self._events.append(ev)
-
-    def instant(self, name: str, cat: str, ts_s: float,
-                tid: int = TID_ENGINE, args: dict | None = None) -> None:
-        ev = {"name": name, "cat": cat, "ph": "i", "s": "t", "pid": self.pid,
-              "tid": tid, "ts": round(ts_s * 1e6, 3)}
-        if args:
-            ev["args"] = args
-        self._events.append(ev)
-
-    def thread_name(self, tid: int, name: str) -> None:
-        self._thread_names.setdefault(tid, name)
-
-    def trace_events(self) -> list[dict]:
-        """Metadata events first, then payload sorted by timestamp (Perfetto
-        tolerates unsorted input; sorted keeps the monotonicity testable)."""
-        meta = [{"name": "process_name", "ph": "M", "pid": self.pid, "tid": 0,
-                 "args": {"name": self.process_name}}]
-        for tid, name in sorted(self._thread_names.items()):
-            meta.append({"name": "thread_name", "ph": "M", "pid": self.pid,
-                         "tid": tid, "args": {"name": name}})
-        return meta + sorted(self._events, key=lambda e: (e["ts"], e["tid"]))
-
-
-def write_trace(path: str, sinks) -> dict:
-    """Merge sinks (one per replica) into one trace-event JSON document."""
-    doc = {"traceEvents": [ev for s in sinks for ev in s.trace_events()],
-           "displayTimeUnit": "ms"}
-    with open(path, "w") as fh:
-        json.dump(doc, fh)
-        fh.write("\n")
-    return doc
-
-
 class EngineProfiler:
-    """Per-engine performance profiler: step cost accounting, roofline /
-    bandwidth gauges, and the tick/request/compile trace timeline.
+    """Per-engine performance profiler: step cost accounting and roofline /
+    bandwidth gauges.
 
     Created by :class:`~repro.serve.telemetry.EngineTelemetry` when profiling
     is configured; the telemetry hub forwards phase sections
-    (:meth:`on_phase`), tick boundaries (:meth:`on_tick`), compile-count
-    bumps (:meth:`compile_event`), and finalization (:meth:`finalize`).
+    (:meth:`on_phase`).
     Everything is lazy: a step's HLO is analyzed the first time its phase
     fires (or on an explicit :meth:`phase_costs` call), out of band of the
     measured sections.
     """
 
-    def __init__(self, engine, registry, *, trace_path: str | None = None,
-                 pid: int = 0, peak_flops: float = PEAK_FLOPS_BF16,
+    def __init__(self, engine, registry, *, peak_flops: float = PEAK_FLOPS_BF16,
                  peak_bw: float = HBM_BW):
         self.engine = engine
         self.registry = registry
-        self.trace_path = trace_path
         self.peak_flops = peak_flops
         self.peak_bw = peak_bw
-        self.sink = TraceEventSink(pid=pid)
         self._costs: dict[str, StepCost | None] = {}
         self._seen_calls: dict[str, int] = {}
         # accumulated (flops, bytes, wall_s, ticks) per phase for run means
         self._accum: dict[str, list[float]] = {p: [0.0, 0.0, 0.0, 0.0]
                                                for p in PHASES}
-        self._finalized = False
-
-    @property
-    def pid(self) -> int:
-        return self.sink.pid
-
-    @pid.setter
-    def pid(self, value: int) -> None:
-        self.sink.pid = int(value)
 
     # -- cost accounting ----------------------------------------------------
 
@@ -289,15 +207,14 @@ class EngineProfiler:
 
     # -- live hooks (called by EngineTelemetry) ------------------------------
 
-    def on_phase(self, phase: str, start_t: float, dur_s: float) -> None:
-        """One tick's phase section finished: trace it and refresh the
-        roofline/bandwidth gauges from (cost per call) x (calls this tick)."""
+    def on_phase(self, phase: str, dur_s: float) -> None:
+        """One tick's phase section finished: refresh the roofline/bandwidth
+        gauges from (cost per call) x (calls this tick)."""
         step = _phase_step(self.engine, phase)
         cost = self.step_cost(step) if step is not None else None
         counter = self.registry.counter(_PHASE_COUNTERS[phase]).value
         ncalls = counter - self._seen_calls.get(phase, 0)
         self._seen_calls[phase] = counter
-        args = {"calls": ncalls}
         if cost is not None and ncalls > 0 and dur_s > 0:
             flops = cost.flops * ncalls
             hbm = cost.hbm_bytes * ncalls
@@ -311,19 +228,8 @@ class EngineProfiler:
             acc[1] += hbm
             acc[2] += dur_s
             acc[3] += 1
-            args.update(gflops=round(flops / 1e9, 3),
-                        mb=round(hbm / 1e6, 3))
-        self.sink.complete(phase, "phase", start_t, dur_s, TID_ENGINE, args)
 
-    def on_tick(self, engine, now: float, wall_s: float) -> None:
-        self.sink.complete("tick", "tick", now, wall_s, TID_ENGINE,
-                           {"step": engine.steps})
-
-    def compile_event(self, step: str, t: float, count: int) -> None:
-        self.sink.instant(f"jit_compile:{step}", "compile", t, TID_ENGINE,
-                          {"compiled_variants": count})
-
-    # -- summaries / export --------------------------------------------------
+    # -- summaries -----------------------------------------------------------
 
     def utilization_summary(self) -> dict:
         """Run-mean utilization per phase: totals over every profiled tick
@@ -346,73 +252,6 @@ class EngineProfiler:
                 "effective_bw_mean": hbm / wall if wall > 0 else None,
             }
         return out
-
-    def add_request_traces(self, traces) -> None:
-        """Render retired requests' lifecycle spans into per-request lanes."""
-        for tr in traces:
-            tid = TID_REQ_BASE + tr.rid
-            self.sink.thread_name(tid, f"req {tr.rid}")
-            for name, a, b in tr.spans():
-                self.sink.complete(name, "request", a, b - a, tid,
-                                   {"rid": tr.rid})
-            for t, n in tr.token_times:
-                self.sink.instant("tokens", "request", t, tid, {"n": n})
-
-    def finalize(self, tracer=None) -> str | None:
-        """Fold completed request traces in and write the trace file (when a
-        path is configured).  Idempotent."""
-        if self._finalized:
-            return self.trace_path
-        self._finalized = True
-        if tracer is not None:
-            self.add_request_traces(tracer.completed)
-        if self.trace_path:
-            write_trace(self.trace_path, [self.sink])
-            return self.trace_path
-        return None
-
-
-def validate_trace(doc: dict) -> list[str]:
-    """Structural validation of a trace-event JSON document (the shape
-    Perfetto's legacy-JSON importer requires).  Returns human-readable
-    errors; empty means loadable."""
-    errors: list[str] = []
-    if not isinstance(doc, dict) or "traceEvents" not in doc:
-        return ["missing traceEvents"]
-    evs = doc["traceEvents"]
-    if not isinstance(evs, list) or not evs:
-        return ["traceEvents must be a non-empty list"]
-    last_ts: dict[tuple, float] = {}
-    for i, ev in enumerate(evs):
-        for key in ("name", "ph", "pid", "tid"):
-            if key not in ev:
-                errors.append(f"event {i}: missing {key}")
-        ph = ev.get("ph")
-        if ph == "M":
-            continue
-        ts = ev.get("ts")
-        if not isinstance(ts, (int, float)) or ts < 0:
-            errors.append(f"event {i}: bad ts {ts!r}")
-            continue
-        if ph == "X":
-            dur = ev.get("dur")
-            if not isinstance(dur, (int, float)) or dur < 0:
-                errors.append(f"event {i}: complete event with bad dur {dur!r}")
-        lane = (ev.get("pid"), ev.get("tid"))
-        if ts < last_ts.get(lane, -np.inf):
-            errors.append(f"event {i}: ts {ts} not monotonic on lane {lane}")
-        last_ts[lane] = ts
-    return errors
-
-
-def validate_trace_file(path: str) -> dict:
-    with open(path) as fh:
-        doc = json.load(fh)
-    errors = validate_trace(doc)
-    if errors:
-        raise ValueError(f"{path} failed trace validation:\n  "
-                         + "\n  ".join(errors))
-    return doc
 
 
 def profile_report(engine, snapshot: dict, *,

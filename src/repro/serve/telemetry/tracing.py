@@ -1,7 +1,25 @@
-"""Request-lifecycle tracing: spans + first-class latency derivation.
+"""Tracing: program spans inside ``Engine.step``, and request lifecycles.
 
-Every request leaves a :class:`RequestTrace` — the event timestamps
-``submit → admit → first_token → retire`` plus per-tick token-emission
+**Program spans.** :func:`span` (a context manager) times one section of the
+engine's host loop — ``serve.step``, ``serve.admit``,
+``serve.decode.{marshal,dispatch,wait,fetch,sample}`` and so on, with their
+counts as attributes.  Each span is written twice:
+
+* into the JAX profiler's host plane, as a ``jax.profiler.TraceAnnotation``
+  — on the same clock as the device ops whenever a profiler trace runs
+  (``jax.profiler.trace(dir)``, the ``--profile-out`` option of the front
+  ends); outside a trace this costs a flag check;
+* into one process-wide bounded ring (:class:`SpanLog`, 65,536 entries) as
+  ``(name, start_ns, end_ns, parent, attrs, sid)`` on ``time.monotonic_ns``,
+  the clock ``Engine.step(now=...)``'s callers use.  ``parent`` is the
+  ``sid`` of the enclosing span, so self time = duration − children.
+  :func:`spans_between` reads it.
+
+The ring is process-wide because the profiler it mirrors is.  A few spans per
+tick cost a couple of clock reads and a deque append each; they are always on.
+
+**Request lifecycles.** Every request leaves a :class:`RequestTrace` — the
+event timestamps ``submit → admit → first_token → retire`` plus per-emission
 timestamps ``(t, n_tokens)``.  From those the tracer derives the serving
 latencies as *first-class metrics* (fed straight into the registry's
 histograms on retire, rather than recomputed by every benchmark):
@@ -14,18 +32,107 @@ histograms on retire, rather than recomputed by every benchmark):
 * ``request_latency_s`` = retire − submit.
 
 Timestamps are whatever clock the engine is driven on — wall time in live
-serving, the virtual clock in ``benchmarks/serve_throughput.py`` — the
-derivations only ever subtract them.  With a trace path configured, each
-retired request is appended as one JSON line (rid, spans, events, token
-timeline, derived latencies); the last ``keep`` completed traces stay
-in memory for tests and post-run inspection.
+serving, the virtual clock in ``launch.serve_engine.run_workload`` — the
+derivations only ever subtract them.  The engine stamps a token when it is
+emitted, after its logits reached the host (tick-start ``now`` plus the time
+the tick has taken so far), so TTFT and TPOT include the step that made it.
+With a trace path configured, each retired request is appended as one JSON
+line (rid, spans, events, token timeline, derived latencies); the last
+``keep`` completed traces stay in memory for tests and post-run inspection.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
+import threading
+import time
 from collections import deque
+from typing import NamedTuple
+
+from jax.profiler import TraceAnnotation
+
+SPAN_LOG_SIZE = 65_536
+
+
+class Span(NamedTuple):
+    """One finished program span, on ``time.monotonic_ns``."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    parent: int | None  # sid of the enclosing span
+    attrs: dict
+    sid: int
+
+
+class SpanLog:
+    """Bounded ring of finished spans: the oldest fall off the end."""
+
+    def __init__(self, maxlen: int = SPAN_LOG_SIZE):
+        self.spans: deque[Span] = deque(maxlen=maxlen)
+
+    def append(self, s: Span) -> None:
+        self.spans.append(s)
+
+    def between(self, lo_ns: int, hi_ns: int) -> list[Span]:
+        """Spans that start and end inside ``[lo_ns, hi_ns]``."""
+        return [s for s in self.spans if s.start_ns >= lo_ns and s.end_ns <= hi_ns]
+
+    def clear(self) -> None:
+        self.spans.clear()
+
+
+LOG = SpanLog()
+_ids = itertools.count(1)
+_open = threading.local()  # per thread: sids of the spans now open
+
+
+def spans_between(lo_ns: int, hi_ns: int) -> list[Span]:
+    """The process's finished spans that lie inside ``[lo_ns, hi_ns]``."""
+    return LOG.between(lo_ns, hi_ns)
+
+
+class span:
+    """``with span("serve.decode.fetch", bytes=n) as sp:`` — one program span,
+    in the profiler's host plane and in :data:`LOG`.  ``sp.set(k=v)`` adds
+    attributes known only inside the section; ``sp.seconds`` is its length
+    (so far, while it is open)."""
+
+    __slots__ = ("name", "attrs", "sid", "parent", "start_ns", "end_ns", "_tm")
+
+    def __init__(self, name: str, **attrs):
+        self.name, self.attrs, self.end_ns = name, attrs, None
+
+    def __enter__(self) -> "span":
+        self._tm = TraceAnnotation(self.name, **self.attrs)
+        self._tm.__enter__()
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        self.parent = stack[-1] if stack else None
+        self.sid = next(_ids)
+        stack.append(self.sid)
+        self.start_ns = time.monotonic_ns()
+        return self
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+        self._tm.set_metadata(**attrs)
+
+    def __exit__(self, *exc) -> None:
+        self.end_ns = time.monotonic_ns()
+        _open.stack.pop()
+        LOG.append(Span(self.name, self.start_ns, self.end_ns, self.parent,
+                        self.attrs, self.sid))
+        self._tm.__exit__(*exc)
+
+    @property
+    def seconds(self) -> float:
+        end = time.monotonic_ns() if self.end_ns is None else self.end_ns
+        return 1e-9 * (end - self.start_ns)
+
 
 SPAN_EVENTS = ("submit", "admit", "first_token", "retire")
 

@@ -1,12 +1,11 @@
-"""Engine performance profiler: trace export, cost accounting, the
-zero-interference contract, and the bench-regression gate.
+"""Engine performance profiler: cost accounting, the zero-interference
+contract under a profiler trace, and the bench-regression gate.
 
 Pinned here:
 
-* Chrome trace-event documents are structurally valid (required keys,
-  per-lane monotonic microsecond timestamps, metadata events) and a real
-  engine run's trace carries tick-phase spans, request-lifecycle spans, and
-  jit-compile events on one shared clock,
+* a real engine run under ``jax.profiler.trace`` writes a trace directory
+  whose host plane carries one ``serve.step`` span per engine tick and the
+  ``serve.prefill.*`` / ``serve.decode.*`` sections inside them,
 * per-call cost accounting is **deterministic**: AOT-lowering the same
   engine's steps twice (and on a freshly-built identical engine) yields
   bit-identical FLOPs/bytes — the HLO is a pure function of the avals,
@@ -22,21 +21,20 @@ Pinned here:
   nonzero accordingly.
 """
 
+import glob
 import json
 
 import jax
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.configs import get_reduced_config
 from repro.models import build_model
 from repro.serve import Engine, EngineConfig, SpecConfig, TelemetryConfig
 from repro.serve.telemetry import CATALOG
-from repro.serve.telemetry.profiling import (EngineProfiler, TraceEventSink,
-                                             profile_report,
-                                             step_example_args,
-                                             validate_trace,
-                                             validate_trace_file, write_trace)
+from repro.serve.telemetry.profiling import (EngineProfiler, profile_report,
+                                             step_example_args)
 from repro.serve.telemetry import regression
 from repro.serve.telemetry.schema import validate_bench
 
@@ -67,53 +65,6 @@ def _run_engine(model, params, cfg, *, telemetry=None, spec=None,
         eng.step(now=t)
         t += 0.01
     return eng, t
-
-
-# ---------------------------------------------------------------------------
-# trace-event sink: schema + timestamps
-# ---------------------------------------------------------------------------
-
-
-def test_trace_sink_schema_and_monotonic_ts(tmp_path):
-    sink = TraceEventSink(pid=0, process_name="engine")
-    sink.complete("tick", "tick", ts_s=0.0, dur_s=0.01)
-    sink.complete("decode", "phase", ts_s=0.001, dur_s=0.005)
-    sink.instant("jit_compile:decode_all", "compile", ts_s=0.002)
-    sink.thread_name(2, "req 0")
-    sink.complete("queued", "request", ts_s=0.0, dur_s=0.01, tid=2)
-    path = str(tmp_path / "trace.json")
-    doc = write_trace(path, [sink])
-    assert validate_trace(doc) == []
-    on_disk = validate_trace_file(path)  # raises on structural problems
-    assert on_disk == doc
-    evs = doc["traceEvents"]
-    # metadata first: process_name + both thread lanes
-    metas = [e for e in evs if e["ph"] == "M"]
-    assert {m["name"] for m in metas} == {"process_name", "thread_name"}
-    # complete events carry microsecond ts/dur
-    tick = next(e for e in evs if e["name"] == "tick")
-    assert tick["ph"] == "X" and tick["dur"] == pytest.approx(10_000)
-    # payload sorted by timestamp within the document
-    payload_ts = [e["ts"] for e in evs if e["ph"] != "M"]
-    assert payload_ts == sorted(payload_ts)
-    # negative durations are clamped, never emitted
-    sink.complete("weird", "phase", ts_s=1.0, dur_s=-5.0)
-    assert all(e.get("dur", 0) >= 0 for e in sink.trace_events())
-
-
-def test_validate_trace_rejects_broken_docs():
-    assert validate_trace({}) == ["missing traceEvents"]
-    assert validate_trace({"traceEvents": []}) != []
-    bad_ts = {"traceEvents": [
-        {"name": "a", "ph": "X", "pid": 0, "tid": 0, "ts": 10.0, "dur": 1.0},
-        {"name": "b", "ph": "X", "pid": 0, "tid": 0, "ts": 5.0, "dur": 1.0}]}
-    assert any("monotonic" in e for e in validate_trace(bad_ts))
-    no_dur = {"traceEvents": [
-        {"name": "a", "ph": "X", "pid": 0, "tid": 0, "ts": 1.0}]}
-    assert any("dur" in e for e in validate_trace(no_dur))
-    missing = {"traceEvents": [{"ph": "X", "ts": 1.0, "dur": 1.0}]}
-    errs = validate_trace(missing)
-    assert any("name" in e for e in errs) and any("pid" in e for e in errs)
 
 
 # ---------------------------------------------------------------------------
@@ -163,15 +114,15 @@ def test_step_example_args_cover_spec_verify(dense_setup):
 def test_zero_interference_profiling_on_vs_off(dense_setup, tmp_path):
     cfg, model, params = dense_setup
     plain, _ = _run_engine(model, params, cfg, telemetry=None)
-    profiled, t = _run_engine(
-        model, params, cfg,
-        telemetry=TelemetryConfig(
-            profile_trace_path=str(tmp_path / "trace.json")))
+    with jax.profiler.trace(str(tmp_path), create_perfetto_trace=True):
+        profiled, t = _run_engine(model, params, cfg,
+                                  telemetry=TelemetryConfig(profile=True))
     # token streams bit-identical
     assert ({r.rid: r.tokens for r in plain.completed}
             == {r.rid: r.tokens for r in profiled.completed})
     # exactly the same step shapes compiled: the profiler's AOT
-    # lower().compile() never populates the call-site jit cache
+    # lower().compile() never populates the call-site jit cache, and the
+    # serve.* spans are host-only
     assert plain.compile_counts() == profiled.compile_counts()
     assert profiled.compile_counts()["decode_all"] == 1
     assert profiled.compile_counts()["prefill_all"] == 1
@@ -190,24 +141,25 @@ def test_zero_interference_profiling_on_vs_off(dense_setup, tmp_path):
                      "roofline_util_", "effective_bw_"):
             assert CATALOG[stem + phase][0] == "gauge"
 
-    doc = validate_trace_file(str(tmp_path / "trace.json"))
-    evs = doc["traceEvents"]
-    cats = {e.get("cat") for e in evs if e["ph"] != "M"}
-    assert {"tick", "phase", "request", "compile"} <= cats
-    # every engine tick got a span, every phase span sits on the tick lane
-    ticks = [e for e in evs if e.get("cat") == "tick"]
+    # the trace directory: an XSpace and a Perfetto trace of the run
+    run_dir = tmp_path / "plugins" / "profile"
+    assert glob.glob(str(run_dir / "*" / "perfetto_trace.json.gz"))
+    xspace = glob.glob(str(run_dir / "*" / "*.xplane.pb"))[0]
+    host = [e for p in ProfileData.from_file(xspace).planes if p.name.startswith("/host")
+            for line in p.lines for e in line.events if e.name.startswith("serve.")]
+    # every engine tick got a serve.step span, counted 1, 2, ...
+    ticks = sorted((e for e in host if e.name == "serve.step"), key=lambda e: e.start_ns)
     assert len(ticks) == snap["counters"]["engine_ticks"]
-    phases = [e for e in evs if e.get("cat") == "phase"]
-    assert {e["name"] for e in phases} <= {"prefill", "decode", "verify"}
-    assert all(e["tid"] == 0 for e in ticks + phases)
-    # request lanes: one queued/prefill/decode span triple per retired request
-    reqs = [e for e in evs if e.get("cat") == "request" and e["ph"] == "X"]
-    assert {e["name"] for e in reqs} == {"queued", "prefill", "decode"}
-    assert len({e["tid"] for e in reqs}) == 3  # one lane per request
-    # compile events name the step and happened on the engine lane
-    compiles = [e for e in evs if e.get("cat") == "compile"]
-    assert {e["name"] for e in compiles} >= {"jit_compile:decode_all",
-                                             "jit_compile:prefill_all"}
+    assert [dict(e.stats)["step"] for e in ticks] == list(range(1, len(ticks) + 1))
+    names = {e.name for e in host}
+    assert {"serve.admit", "serve.prefill.dispatch", "serve.decode.dispatch",
+            "serve.decode.wait", "serve.decode.fetch", "serve.decode.sample",
+            "serve.telemetry"} <= names
+    # sections sit inside their tick
+    for e in host:
+        if e.name != "serve.step":
+            assert any(t.start_ns <= e.start_ns and e.start_ns + e.duration_ns
+                       <= t.start_ns + t.duration_ns for t in ticks), e.name
 
 
 def test_profiling_off_has_no_profiler(dense_setup):

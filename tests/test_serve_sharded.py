@@ -239,19 +239,22 @@ def test_replicated_engine_unique_rids_and_merge_order():
 
 @needs2
 def test_dp_aggregate_pools_histograms_and_merges_trace(tmp_path):
+    import glob
+
+    from jax.profiler import ProfileData
+
     from repro.serve import TelemetryConfig
-    from repro.serve.telemetry.profiling import validate_trace_file
 
     cfg, model, params = _setup("qwen3-1.7b")
     eng = make_engine(model, params, EngineConfig(
         n_slots=2, max_len=64, page_size=8, kv_dtype="mxfp4", prefill_chunk=8,
         sharding=ShardingConfig(tp=1, dp=2),
-        telemetry=TelemetryConfig(
-            profile_trace_path=str(tmp_path / "dp_trace.json"))))
+        telemetry=TelemetryConfig(profile=True)))
     assert isinstance(eng, ReplicatedEngine)
     for p in _prompts(cfg):
         eng.submit(p, 8, arrival_time=0.0)
-    eng.drain()
+    with jax.profiler.trace(str(tmp_path)):
+        eng.drain()
     assert all(e.completed for e in eng.engines)  # placer spread the work
 
     agg = eng.aggregate_telemetry()
@@ -273,10 +276,11 @@ def test_dp_aggregate_pools_histograms_and_merges_trace(tmp_path):
     # profiler gauges averaged across replicas, nonzero with profiling on
     assert agg["gauges"]["roofline_util_decode"] > 0
 
-    # one merged Perfetto document: a process lane per replica
-    path = eng.write_profile()
-    doc = validate_trace_file(path)
-    payload = [e for e in doc["traceEvents"] if e["ph"] != "M"]
-    assert {e["pid"] for e in payload} == {0, 1}
-    cats = {e.get("cat") for e in payload}
-    assert {"tick", "phase", "request"} <= cats
+    # one profiler trace: each replica's ticks tagged with its index, one
+    # serve.step span per replica tick
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))[0]
+    steps = [e for p in ProfileData.from_file(path).planes if p.name.startswith("/host")
+             for line in p.lines for e in line.events if e.name == "serve.step"]
+    replicas = [dict(e.stats)["replica"] for e in steps]
+    assert set(replicas) == {0, 1}
+    assert len(steps) == agg["counters"]["engine_ticks"]
