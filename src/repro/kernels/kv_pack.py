@@ -55,22 +55,60 @@ def split_nibbles(packed: jnp.ndarray) -> jnp.ndarray:
                      axis=-1).reshape(*lead, kh * 2)
 
 
+def _e2m1_value(nib: jnp.ndarray) -> jnp.ndarray:
+    """int32 nibble codes (S EE M) → f32 E2M1 values, by arithmetic."""
+    idx = nib & 7
+    mag_norm = _exp2i((idx - 2) >> 1) * (1.0 + 0.5 * (idx & 1).astype(jnp.float32))
+    mag = jnp.where(idx >= 2, mag_norm, idx.astype(jnp.float32) * 0.5)
+    return jnp.where((nib & 8) > 0, -mag, mag)
+
+
 def unpack_dequant(packed: jnp.ndarray, scale_codes: jnp.ndarray,
                    block: int = GROUP) -> jnp.ndarray:
     """Packed nibble codes [..., K/2] u8 + E8M0 scale codes [..., K/block] u8
     → f32 values [..., K].  Pure arithmetic (no table gathers) so it lowers
-    on the VPU — shared by the unpack kernel below and the fused paged-
-    attention kernel (``kernels/paged_attention.py``), which calls it per
-    VMEM-resident KV tile."""
-    nib = split_nibbles(packed.astype(jnp.int32))
-    idx = nib & 7
-    mag_norm = _exp2i((idx - 2) >> 1) * (1.0 + 0.5 * (idx & 1).astype(jnp.float32))
-    mag = jnp.where(idx >= 2, mag_norm, idx.astype(jnp.float32) * 0.5)
-    val = jnp.where((nib & 8) > 0, -mag, mag)
+    on the VPU — the body of the unpack kernel below."""
+    val = _e2m1_value(split_nibbles(packed.astype(jnp.int32)))
     # per-element scale by lane repeat: Mosaic refuses the [..., K/block,
     # block] lane-splitting reshape
     scale = _exp2i(scale_codes.astype(jnp.int32) - 127)
     return val * jnp.repeat(scale, block, axis=-1)
+
+
+def unpack_dequant_split(packed: jnp.ndarray, scale_codes: jnp.ndarray,
+                         block: int = GROUP) -> jnp.ndarray:
+    """:func:`unpack_dequant` with the K values in *split order*: the high
+    nibbles' elements (even indices) in lanes ``[0, K/2)``, the low nibbles'
+    (odd indices) in ``[K/2, K)``.  Each value is the same product of the
+    same code and scale; only its lane differs.  The halves sit side by side
+    instead of interleaved, so no lane shuffle is needed: the interleave
+    costs Mosaic seconds of compile per [256, K/2] tile and most of the
+    unpack's VPU work.  The fused paged-attention kernel
+    (``kernels/paged_attention.py``) calls it per VMEM-resident KV block and
+    puts its queries and outputs in the same order."""
+    packed = packed.astype(jnp.int32)
+    half = packed.shape[-1]
+    val = _e2m1_value(jnp.concatenate([(packed >> 4) & 0xF, packed & 0xF], axis=-1))
+    scale = _exp2i(scale_codes.astype(jnp.int32) - 127)
+    # lane l holds element 2(l mod K/2) (+1 in the upper half), whose scale
+    # block is (l mod K/2) // (block/2): a select per block, no lane repeat
+    lane = jax.lax.broadcasted_iota(jnp.int32, val.shape, val.ndim - 1)
+    blk = jnp.where(lane >= half, lane - half, lane) // (block // 2)
+    s = scale[..., 0:1]
+    for i in range(1, scale.shape[-1]):
+        s = jnp.where(blk == i, scale[..., i:i + 1], s)
+    return val * s
+
+
+def split_order(x: jnp.ndarray) -> jnp.ndarray:
+    """[..., K] → the split order of :func:`unpack_dequant_split`."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def interleave_order(x: jnp.ndarray) -> jnp.ndarray:
+    """Inverse of :func:`split_order`."""
+    *lead, k = x.shape
+    return jnp.stack([x[..., :k // 2], x[..., k // 2:]], axis=-1).reshape(*lead, k)
 
 
 def _kv_quant_pack_kernel(x_ref, codes_ref, scales_ref):

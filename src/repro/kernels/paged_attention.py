@@ -1,23 +1,40 @@
-"""Fused MXFP4 paged-attention decode kernel (Pallas).
+"""Fused MXFP4 paged attention (Pallas): decode, verify and prefill over the
+packed KV pool.
 
 Batched decode attends *directly over the packed KV pool*: the per-slot page
-table (scalar-prefetched, so it is available before the kernel body runs)
-drives the KV block fetch via the ``BlockSpec`` index map — page ``p`` of
-slot ``b`` pulls pool page ``tables[b, p]`` into VMEM.  E2M1 nibble codes and
-E8M0 scale bytes are unpacked/dequantized per tile *inside* the kernel
-(``kv_pack.unpack_dequant`` — pure arithmetic, VPU-friendly), so decode HBM
+table and lengths are scalar-prefetched (in SMEM before the body runs) and
+drive the KV fetch through the ``BlockSpec`` index maps.  E2M1 nibble codes
+and E8M0 scale bytes are unpacked/dequantized per block *inside* the kernel
+(``kv_pack.unpack_dequant_split`` — pure arithmetic on the VPU), so HBM
 traffic is O(packed KV) = 4.25 bits/element instead of the O(dense KV)
-gather-dequantize round-trip the engine previously paid.
+gather-dequantize round-trip.
 
-Blocking is GQA-native: the grid is ``(B, Hkv, pages_per_slot)`` with pages
-innermost; each (slot, KV-head) program streams that head's pages once and
-computes all ``Hq/Hkv`` query heads of the group against it — KV heads are
-read in place, never materialized ``group×`` (no ``jnp.repeat``).  Online
-softmax state (m, l, acc) lives in VMEM scratch across the page loop;
-per-slot valid-length masking (``lengths[b]``, i.e. decode position + 1)
-handles ragged batches, and fully-invalid pages are skipped with ``pl.when``
-(their DMA fetches the scratch page the allocator parks unmapped table
-entries on).
+**The walk.**  The grid is ``(B, Hkv / hb, ceil(P / ppb))`` for ``P`` page
+columns: one step per (slot, head block, block of ``ppb`` pages).  A step
+reads ``ppb`` pages × ``hb`` KV heads — per page and pool leaf one
+``[hb, ps, payload]`` slab, a single DMA — and the pipeline issues the next
+step's whole wave while this one computes (double-buffered).  Each slot's
+walk stops at its live pages, ``ceil((lengths[b] + n_q - 1) / ps)``: past
+the last live block every page index repeats, so the pipeline fetches
+nothing and the body is skipped; a dead column inside the last live block
+re-uses a page the step already holds.  No dead page is ever read (a page
+table may point dead columns anywhere).  A slot at length 1 — a masked lane
+on the scratch page — walks one block.
+
+**Block sizes follow the shapes** (:func:`block_sizes`): a wave carries
+about ``WAVE_TOKENS`` = 256 tokens (``ppb = 256 / ps`` pages, at most the
+table's width), and ``hb`` is the largest divisor of Hkv whose VMEM
+footprint (q and output blocks, the running m/l/acc, both wave buffers, one
+head's scores and K/V) fits ``VMEM_BUDGET``: every head in decode, fewer
+where wide prefill rows need the room.  No option chooses them.
+
+GQA is native: each head of a block scores all ``Hq/Hkv`` query heads of its
+group against that head's K/V, read in place (no ``jnp.repeat``), with f32
+scores, ``1/sqrt(hd)`` scaling and an online softmax (m, l, acc in VMEM
+scratch) over the blocks; the output is cast to ``q.dtype``.  In the packed
+pool the K/V values come out in *split order* (the even head dims in the
+first half of the lanes, the odd in the second: no lane interleave), so the
+wrapper puts q in the same order (q·k is the same sum) and the output back.
 
 Queries may carry a token axis (``q [B, S, Hq, hd]``): the speculative
 verify scores the last accepted token plus S-1 drafted tokens per slot in
@@ -26,7 +43,7 @@ row's causal bound is offset by its token index (row ``r`` sees positions
 ≤ ``lengths[b] - 1 + r // group``), so drafts never attend past themselves.
 Plain decode is the S == 1 special case of the same kernel.  **Batched paged
 prefill** is the S == prefill_chunk case: every prefilling slot's chunk is
-scored in one grid pass over the packed pool, with ragged tails handled by
+scored in one pass over the packed pool, with ragged tails handled by
 :func:`prefill_chunk_layout` — padding tokens are positioned on a sentinel
 scratch column appended to the page table, so their quantize-on-write lands
 on page 0 and their (garbage) output rows carry per-row causal bounds past
@@ -39,8 +56,9 @@ slice sees the same page mapping.  ``models.attention`` dispatches to this
 kernel whenever the decode cache is a ``PagedKV`` (see its backend matrix).
 
 Validated in interpret mode against ``models.attention.blocked_attention``
-over page-size / GQA / ragged-length / dense-vs-mxfp4 sweeps in
-tests/test_paged_attention.py.
+over page-size / GQA / ragged-length / multi-block / dense-vs-mxfp4 sweeps,
+and with every dead page poisoned, in tests/test_paged_attention.py; the
+Mosaic lowering at the benchmark cells' shapes in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -58,7 +76,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import formats as F
 from repro.core import quantizers as Q
 from repro.kernels.backend import interpret_mode
-from repro.kernels.kv_pack import unpack_dequant
+from repro.kernels.kv_pack import interleave_order, split_order, unpack_dequant_split
 
 GROUP = 32
 NEG_INF = -1e30
@@ -166,15 +184,82 @@ def prefill_chunk_layout(
 
 
 # ---------------------------------------------------------------------------
+# block sizes (from static shapes only)
+# ---------------------------------------------------------------------------
+
+WAVE_TOKENS = 256  # KV tokens a DMA wave aims to carry (pages_per_block · ps)
+VMEM_LIMIT = 32 * 2**20  # scoped VMEM the kernel asks Mosaic for
+VMEM_BUDGET = 24 * 2**20  # what the block choice may fill of it
+
+
+def _tile_bytes(shape, itemsize: int) -> int:
+    """VMEM bytes of an array whose last two dims are padded to the TPU tile:
+    128 lanes by 8 sublanes of 32 bits (16 rows of bf16, 32 of u8)."""
+    *lead, r, c = shape
+    sub = 8 * (4 // itemsize)
+    return (int(np.prod(lead, dtype=np.int64)) * -(-r // sub) * sub
+            * -(-c // 128) * 128 * itemsize)
+
+
+def block_sizes(ps: int, n_heads: int, hd: int, rows: int, n_pp: int,
+                leaves, q_itemsize: int) -> tuple[int, int]:
+    """``(pages_per_block, heads_per_block)`` for one kernel call.
+
+    A wave carries about ``WAVE_TOKENS`` tokens (never more pages than a
+    slot has).  The head block is the largest divisor of ``n_heads`` whose
+    VMEM footprint fits ``VMEM_BUDGET``: the q and output blocks (double-
+    buffered by the pipeline), the running (m, l, acc), both buffers of every
+    leaf's wave, and one head's scores and dequantized K/V.  ``leaves`` is
+    ``[(payload, itemsize), ...]`` of the pool leaves.  Decode rows are few,
+    so every head fits; wide prefill rows may split the heads."""
+    ppb = max(1, min(n_pp, WAVE_TOKENS // ps))
+    T = ppb * ps
+    for hb in sorted((d for d in range(1, n_heads + 1) if n_heads % d == 0),
+                     reverse=True):
+        need = (4 * _tile_bytes((hb, rows, hd), q_itemsize)
+                + 2 * _tile_bytes((hb, rows, 1), 4)
+                + _tile_bytes((hb, rows, hd), 4)
+                + sum(2 * _tile_bytes((ppb * hb, ps, w), isz) for w, isz in leaves)
+                + 4 * _tile_bytes((rows, T), 4)
+                + 8 * _tile_bytes((T, hd), 4))
+        if need <= VMEM_BUDGET:
+            return ppb, hb
+    return ppb, 1
+
+
+def walk_pages(tables: jnp.ndarray, lengths: jnp.ndarray, n_q: int, ps: int,
+               ppb: int) -> jnp.ndarray:
+    """The pool page each step of the walk reads: [B, n_blocks · ppb], entry
+    ``j·ppb + c`` for column ``c`` of page block ``j``.
+
+    A slot's live columns are ``ceil((lengths[b] + n_q - 1) / ps)`` (at least
+    one).  Past its last live block every block repeats that block's pages,
+    so the pipeline fetches nothing there.  A dead column inside the last
+    live block keeps the page its buffer holds from the block before (or, in
+    a one-block walk, takes the last live page).  So no dead page is ever
+    read, wherever the table points its dead columns."""
+    B, n_pp = tables.shape
+    n_blk = -(-n_pp // ppb)
+    n_live = jnp.maximum(-(-(lengths + n_q - 1) // ps), 1)[:, None, None]
+    j = jnp.arange(n_blk, dtype=jnp.int32)[None, :, None]
+    c = jnp.arange(ppb, dtype=jnp.int32)[None, None, :]
+    jj = jnp.minimum(j, -(-n_live // ppb) - 1)
+    col = jj * ppb + c
+    col = jnp.where(col < n_live, col, jnp.where(jj > 0, col - ppb, n_live - 1))
+    col = jnp.minimum(col, n_pp - 1).reshape(B, n_blk * ppb)
+    return jnp.take_along_axis(tables, col, axis=1)
+
+
+# ---------------------------------------------------------------------------
 # kernel bodies
 # ---------------------------------------------------------------------------
 
 
 def _online_softmax_tile(q, k, v, kv_pos, q_pos, m_ref, l_ref, acc_ref):
-    """One [rows, ps] score tile folded into the running (m, l, acc);
+    """One [rows, T] score tile folded into the running (m, l, acc);
     ``q_pos`` is [rows, 1] — each query row carries its own causal bound."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [rows, ps]
+                            preferred_element_type=jnp.float32)  # [rows, T]
     s = jnp.where(kv_pos <= q_pos, s, NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -186,54 +271,71 @@ def _online_softmax_tile(q, k, v, kv_pos, q_pos, m_ref, l_ref, acc_ref):
     m_ref[...] = m_new
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, *rest,
-                  load_kv, ps: int, n_pp: int, group: int, n_q: int,
-                  scale: float):
-    """One (slot, KV-head, page) step; ``load_kv(kv_refs)`` materializes the
-    page's [ps, hd] f32 K/V tiles (pool-dtype-specific — the only part that
-    differs between the packed and dense pools).
+def _paged_kernel(walk_ref, len_ref, q_ref, *rest, n_leaves: int, load_kv,
+                  ps: int, ppb: int, n_blk_max: int, hb: int, group: int,
+                  n_q: int, scale: float):
+    """One (slot, head block, page block) step: ``ppb`` pages of ``hb`` KV
+    heads, fetched by the pipeline one step ahead as one DMA wave
+    (``walk_ref``, from :func:`walk_pages`, names the pages).
 
-    The query block is [n_q·group, hd]: ``n_q`` consecutive decode/verify
-    tokens × the KV head's GQA group.  Row ``r`` belongs to query token
-    ``r // group`` sitting at absolute position ``len_ref[b] - 1 + r//group``
-    — speculative verify scores all drafted tokens in one pass with per-row
-    causal bounds; plain decode is the n_q == 1 special case."""
-    *kv_refs, o_ref, m_ref, l_ref, acc_ref = rest
-    b, p = pl.program_id(0), pl.program_id(2)
+    ``rest`` holds the wave's KV tiles (``ppb`` refs per pool leaf, each
+    [1, hb, ps, payload]), the output block and the running (m, l, acc).
+    ``load_kv(kv_refs, h)`` materializes head ``h``'s [T, hd] f32 K/V tiles
+    of the block (pool-dtype-specific — the only part that differs between
+    the packed and dense pools).
 
-    @pl.when(p == 0)
+    The query block is [hb, n_q·group, hd]: ``n_q`` consecutive decode/verify
+    /prefill tokens × each KV head's GQA group.  Row ``r`` belongs to query
+    token ``r // group`` sitting at absolute position ``len_ref[b] - 1 +
+    r//group``; plain decode is the n_q == 1 special case."""
+    kv_refs = [rest[i * ppb:(i + 1) * ppb] for i in range(n_leaves)]
+    o_ref, m_ref, l_ref, acc_ref = rest[n_leaves * ppb:]
+    b, j = pl.program_id(0), pl.program_id(2)
+    T = ppb * ps
+    length = len_ref[b]  # tokens visible to the FIRST query row
+    live = length + n_q - 1  # tokens visible to the LAST query row
+
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[b]  # tokens visible to the FIRST query row
-
-    @pl.when(p * ps < length + n_q - 1)
+    @pl.when(j < pl.cdiv(live, T))
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # [n_q*group, hd]
-        k, v = load_kv(kv_refs)
-        kv_pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        kv_pos = j * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
         rows = jax.lax.broadcasted_iota(jnp.int32, (n_q * group, 1), 0)
         q_pos = length - 1 + rows // group
-        _online_softmax_tile(q, k, v, kv_pos, q_pos, m_ref, l_ref, acc_ref)
 
-    @pl.when(p == n_pp - 1)
+        @pl.loop(0, hb)
+        def _head(h):
+            q = q_ref[0, h].astype(jnp.float32) * scale  # [n_q*group, hd]
+            k, v = load_kv(kv_refs, h)
+            _online_softmax_tile(q, k, v, kv_pos, q_pos,
+                                 m_ref.at[h], l_ref.at[h], acc_ref.at[h])
+
+    @pl.when(j == n_blk_max - 1)
     def _flush():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _cat(refs, h, dtype):
+    """Head ``h`` of a wave's pages, stacked along tokens: [ppb·ps, payload]."""
+    return jnp.concatenate([r[0, h].astype(dtype) for r in refs], axis=0)
 
 
 def _load_kv_mxfp4(block: int):
-    def load(kv_refs):
-        kc, ks, vc, vs = kv_refs
-        return (unpack_dequant(kc[0, 0], ks[0, 0], block),
-                unpack_dequant(vc[0, 0], vs[0, 0], block))
+    """K/V in the split head-dim order of ``unpack_dequant_split``; the
+    wrapper puts q in the same order and the output back."""
+    def load(kv_refs, h):
+        kc, ks, vc, vs = (_cat(refs, h, jnp.int32) for refs in kv_refs)
+        return unpack_dequant_split(kc, ks, block), unpack_dequant_split(vc, vs, block)
     return load
 
 
-def _load_kv_dense(kv_refs):
-    k_ref, v_ref = kv_refs
-    return k_ref[0, 0].astype(jnp.float32), v_ref[0, 0].astype(jnp.float32)
+def _load_kv_dense(kv_refs, h):
+    k, v = kv_refs
+    return _cat(k, h, jnp.float32), _cat(v, h, jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -250,75 +352,76 @@ def paged_attention(
     #                        (its position + 1); query s sees lengths + s
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Decode/verify attention directly over the paged pool.
+    """Decode/verify/prefill attention directly over the paged pool.
 
     ``q`` may carry a token axis S > 1 (speculative verify: the last accepted
-    token plus the drafted suffix) — all S tokens of a slot are scored in one
-    grid pass with per-row causal bounds.  Returns [B, Hq, hd] for 3-d ``q``,
-    [B, S, Hq, hd] for 4-d."""
+    token plus the drafted suffix; batched prefill: a chunk) — all S tokens
+    of a slot are scored in one pass with per-row causal bounds.  Returns
+    [B, Hq, hd] for 3-d ``q``, [B, S, Hq, hd] for 4-d."""
     multi = q.ndim == 4
     if not multi:
         q = q[:, None]
     B, S, Hq, hd = q.shape
-    quantized = "k_codes" in pool
-    kleaf = pool["k_codes"] if quantized else pool["k"]
-    Hkv, ps = kleaf.shape[1], kleaf.shape[2]
+    leaf_names = (("k_codes", "k_scales", "v_codes", "v_scales")
+                  if "k_codes" in pool else ("k", "v"))
+    operands = tuple(pool[n] for n in leaf_names)
+    Hkv, ps = operands[0].shape[1], operands[0].shape[2]
     group = Hq // Hkv
     n_pp = tables.shape[1]
-    scale = 1.0 / np.sqrt(hd)
+    rows = S * group
+    ppb, hb = block_sizes(
+        ps, Hkv, hd, rows, n_pp,
+        [(x.shape[3], x.dtype.itemsize) for x in operands], q.dtype.itemsize)
+    quantized = len(operands) == 4
+    load_kv = _load_kv_mxfp4(quant_block(hd)) if quantized else _load_kv_dense
     # [B, S, Hkv, group, hd] → [B, Hkv, S·group, hd]: row r = token r//group,
-    # query head (r%group) of the program's KV head
+    # query head (r%group) of its KV head
     qg = (q.reshape(B, S, Hkv, group, hd)
           .transpose(0, 2, 1, 3, 4)
-          .reshape(B, Hkv, S * group, hd))
+          .reshape(B, Hkv, rows, hd))
+    if quantized:  # q·k is the same sum with both in split order
+        qg = split_order(qg)
 
-    def kv_idx(b, h, p, tbl, ln):
-        del ln
-        return (tbl[b, p], h, 0, 0)
+    walk = walk_pages(tables, lengths, S, ps, ppb)
+    n_blk_max = walk.shape[1] // ppb
 
-    def q_idx(b, h, p, tbl, ln):
-        del p, tbl, ln
-        return (b, h, 0, 0)
+    def q_idx(b, g, j, wk, ln):
+        del j, wk, ln
+        return (b, g, 0, 0)
 
-    if quantized:
-        block = quant_block(hd)
-        load_kv = _load_kv_mxfp4(block)
-        kv_specs = [
-            pl.BlockSpec((1, 1, ps, hd // 2), kv_idx),
-            pl.BlockSpec((1, 1, ps, hd // block), kv_idx),
-            pl.BlockSpec((1, 1, ps, hd // 2), kv_idx),
-            pl.BlockSpec((1, 1, ps, hd // block), kv_idx),
-        ]
-        operands = (pool["k_codes"], pool["k_scales"],
-                    pool["v_codes"], pool["v_scales"])
-    else:
-        load_kv = _load_kv_dense
-        kv_specs = [
-            pl.BlockSpec((1, 1, ps, hd), kv_idx),
-            pl.BlockSpec((1, 1, ps, hd), kv_idx),
-        ]
-        operands = (pool["k"], pool["v"])
-    rows = S * group
-    kern = functools.partial(_paged_kernel, load_kv=load_kv, ps=ps, n_pp=n_pp,
-                             group=group, n_q=S, scale=scale)
+    def kv_idx(c):
+        def idx(b, g, j, wk, ln):
+            del ln
+            return (wk[b, j * ppb + c], g, 0, 0)
+        return idx
 
+    kern = functools.partial(
+        _paged_kernel, n_leaves=len(operands), load_kv=load_kv, ps=ps, ppb=ppb,
+        n_blk_max=n_blk_max, hb=hb, group=group, n_q=S, scale=1.0 / np.sqrt(hd))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, n_pp),
-        in_specs=[pl.BlockSpec((1, 1, rows, hd), q_idx), *kv_specs],
-        out_specs=pl.BlockSpec((1, 1, rows, hd), q_idx),
+        grid=(B, Hkv // hb, n_blk_max),
+        in_specs=[pl.BlockSpec((1, hb, rows, hd), q_idx),
+                  *[pl.BlockSpec((1, hb, ps, x.shape[3]), kv_idx(c))
+                    for x in operands for c in range(ppb)]],
+        out_specs=pl.BlockSpec((1, hb, rows, hd), q_idx),
         scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),  # running max
-            pltpu.VMEM((rows, 1), jnp.float32),  # running denom
-            pltpu.VMEM((rows, hd), jnp.float32),  # running numerator
+            pltpu.VMEM((hb, rows, 1), jnp.float32),  # running max
+            pltpu.VMEM((hb, rows, 1), jnp.float32),  # running denom
+            pltpu.VMEM((hb, rows, hd), jnp.float32),  # running numerator
         ],
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret_mode(interpret),
-    )(tables, lengths, qg, *operands)
+    )(walk, lengths, qg, *[x for x in operands for _ in range(ppb)])
+    if quantized:
+        out = interleave_order(out)
     out = (out.reshape(B, Hkv, S, group, hd)
            .transpose(0, 2, 1, 3, 4)
            .reshape(B, S, Hq, hd))

@@ -613,7 +613,9 @@ class Engine:
                     self.cache.pool, jnp.asarray(self.cache.tables), jnp.asarray(mask))
         valid = int(sum(n for _, _, n in batch))
         with span("serve.prefill.dispatch", slots=len(batch), rows_valid=valid,
-                  rows_computed=int(tokens.size)):
+                  rows_computed=int(tokens.size),
+                  **self._page_walk([pos + 1 for _, pos, _ in batch],
+                                    self.config.prefill_chunk)):
             logits, self.cache.pool = self._prefill_all(self.params, *args)
         reg = self.telemetry.registry
         reg.counter("prefill_calls").inc()
@@ -678,6 +680,18 @@ class Engine:
         self.telemetry.tracer.tokens(req.rid, t, 1)
         self.telemetry.registry.counter("tokens_generated").inc()
 
+    def _page_walk(self, lengths, n_q: int) -> dict:
+        """Span attributes of a paged-attention dispatch: ``pages_live``, the
+        page columns the kernel walks (``ceil((len + n_q - 1) / ps)`` for
+        each dispatched slot, ``len`` the tokens its first query row sees),
+        beside ``page_columns``, the ``n_slots x pages_per_slot`` of the page
+        tables.  Empty unless the kernel does the attention."""
+        if self.decode_backend != "paged":
+            return {}
+        ps = self.config.page_size
+        return {"pages_live": sum(-(-(ln + n_q - 1) // ps) for ln in lengths),
+                "page_columns": int(self.cache.tables.size)}
+
     def _decode_tick(self, decoding: list[Request]) -> None:
         B = self.config.n_slots
         with span("serve.decode.marshal"):
@@ -702,7 +716,8 @@ class Engine:
             else:
                 args += (self.cache.caches,)
             args += (jnp.asarray(mask),)
-        with span("serve.decode.dispatch", slots=len(decoding)):
+        with span("serve.decode.dispatch", slots=len(decoding),
+                  **self._page_walk([int(positions[r.slot]) + 1 for r in decoding], 1)):
             if self.paged:
                 logits, self.cache.pool = self._decode_all(*args)
             elif self.backend == "statepool":
@@ -767,7 +782,8 @@ class Engine:
             self._cow_guard([(r.slot, int(start[r.slot]), k + 1) for r in decoding])
             args = (jnp.asarray(tokens), jnp.asarray(start), self.cache.pool,
                     jnp.asarray(self.cache.tables), jnp.asarray(mask))
-        with span("serve.verify.dispatch", slots=len(decoding)):
+        with span("serve.verify.dispatch", slots=len(decoding),
+                  **self._page_walk([int(start[r.slot]) + 1 for r in decoding], k + 1)):
             logits, self.cache.pool = self._verify_all(self.params, *args)
         logits_np = self._fetch_logits("verify", logits)  # [B, k+1, V]
         t = self._emit_time()

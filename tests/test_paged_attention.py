@@ -44,11 +44,19 @@ def _empty_pool(mode: str, n_pages: int, ps: int, Hkv: int, hd: int) -> dict:
             "v_scales": jnp.zeros((n_pages, Hkv, ps, nb), jnp.uint8)}
 
 
+def _scatter_prefix(pool, tables, k, v, counts, ps):
+    """Write positions ``[0, counts[b])`` of every slot through the engine's
+    write path (``scatter_token``, quantize-on-write per token), in one call."""
+    b, t = np.nonzero(np.arange(k.shape[1])[None, :] < np.asarray(counts)[:, None])
+    return PA.scatter_token(pool, tables[b, t // ps], jnp.asarray(t % ps),
+                            k[b, t], v[b, t])
+
+
 def _paged_setup(mode, lengths, ps, Hkv, hd, pages_per_slot, seed=0):
-    """Random KV scattered token-by-token into a pool (quantize-on-write in
-    mxfp4 mode) + page tables with low ids first — exactly the engine's
-    write path.  Returns (pool, tables, k_dense, v_dense) where the dense
-    arrays hold the values the pool effectively stores."""
+    """Random KV scattered into a pool (quantize-on-write in mxfp4 mode) +
+    page tables with low ids first — exactly the engine's write path.
+    Returns (pool, tables, k_dense, v_dense) where the dense arrays hold the
+    values the pool effectively stores."""
     rng = np.random.default_rng(seed)
     B = len(lengths)
     T = pages_per_slot * ps
@@ -63,11 +71,7 @@ def _paged_setup(mode, lengths, ps, Hkv, hd, pages_per_slot, seed=0):
             tables[b, p] = nxt
             nxt += 1
     tables = jnp.asarray(tables)
-    for b in range(B):
-        for t in range(lengths[b]):
-            pool = PA.scatter_token(
-                pool, tables[b, t // ps][None], jnp.array([t % ps]),
-                k[b, t][None], v[b, t][None])
+    pool = _scatter_prefix(pool, tables, k, v, lengths, ps)
     if mode == "mxfp4":
         fmt = PA.quant_fmt(hd)
         k = Q.kv_dequantize(Q.kv_quantize(k, fmt), fmt)
@@ -75,8 +79,8 @@ def _paged_setup(mode, lengths, ps, Hkv, hd, pages_per_slot, seed=0):
     return pool, tables, k, v
 
 
-def _run_both(mode, lengths, ps, Hkv, group, hd=32, seed=0):
-    pages_per_slot = max(-(-max(lengths) // ps), 2)
+def _run_both(mode, lengths, ps, Hkv, group, hd=32, seed=0, pages_per_slot=None):
+    pages_per_slot = pages_per_slot or max(-(-max(lengths) // ps), 2)
     pool, tables, k, v = _paged_setup(mode, lengths, ps, Hkv, hd,
                                       pages_per_slot, seed)
     B, Hq = len(lengths), Hkv * group
@@ -95,37 +99,86 @@ def _run_both(mode, lengths, ps, Hkv, group, hd=32, seed=0):
 
 
 @pytest.mark.kernels
-@pytest.mark.parametrize("ps", [4, 8, 16])
-@pytest.mark.parametrize("group", [1, 2, 4])
-def test_paged_kernel_parity_dense(ps, group):
-    lengths = [7, 1, 2 * ps, ps + 3]  # ragged, incl. single-token + page-exact
-    out, ref, _ = _run_both("dense", lengths, ps, Hkv=2, group=group)
+@pytest.mark.parametrize("hd", [16, 32, 64, 128])
+def test_split_order_dequant_is_the_same_values(hd):
+    """The kernel's split-order unpack gives every value bit for bit as
+    ``unpack_dequant`` does, only in lanes [even dims | odd dims]; the
+    wrapper's two reorders are inverses."""
+    from repro.kernels import kv_pack
+
+    block = PA.quant_block(hd)
+    rng = np.random.default_rng(hd)
+    codes = jnp.asarray(rng.permutation(np.resize(np.arange(256), (64, hd // 2))),
+                        jnp.uint8)  # every byte value
+    scales = jnp.asarray(rng.integers(0, 256, (64, hd // block)), jnp.uint8)
+    ref = kv_pack.unpack_dequant(codes, scales, block)
+    got = kv_pack.unpack_dequant_split(codes, scales, block)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(kv_pack.split_order(ref)))
+    np.testing.assert_array_equal(np.asarray(kv_pack.interleave_order(got)),
+                                  np.asarray(ref))
+
+
+# Long walks: a wave carries WAVE_TOKENS = 256 tokens (16 pages of 16, 32 of
+# 8), and the 40 page columns are not a whole number of blocks.  The slots
+# cross several blocks and end inside one, sit at length 1 (a masked lane),
+# fill all 40 columns (max_len) or hold a single page.
+LONG_WALK = {16: [600, 1, 640, 16], 8: [300, 1, 320, 8]}
+LONG_PAGES = 40
+
+
+def test_long_walk_shapes_cross_blocks():
+    """The long-walk cases below take several blocks, end inside one, and
+    use a block size that does not divide the pages per slot."""
+    for ps, lengths in LONG_WALK.items():
+        ppb, _ = PA.block_sizes(ps, 2, 32, 2, LONG_PAGES, [(16, 1), (1, 1)] * 2, 4)
+        T = ppb * ps
+        assert T == PA.WAVE_TOKENS and LONG_PAGES % ppb
+        assert max(lengths) == LONG_PAGES * ps > T and lengths[0] % T
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("ps,group,lengths,pages_per_slot", [
+    *[pytest.param(ps, g, [7, 1, 2 * ps, ps + 3], None, id=f"{g}-{ps}")
+      for g in (1, 2, 4) for ps in (4, 8, 16)],  # ragged, single-token, page-exact
+    pytest.param(16, 2, LONG_WALK[16], LONG_PAGES, id="2-16-long-walk"),
+    pytest.param(8, 1, LONG_WALK[8], LONG_PAGES, id="1-8-long-walk"),
+])
+def test_paged_kernel_parity_dense(ps, group, lengths, pages_per_slot):
+    out, ref, _ = _run_both("dense", lengths, ps, Hkv=2, group=group,
+                            pages_per_slot=pages_per_slot)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=0, atol=1e-5)
 
 
 @pytest.mark.kernels
-@pytest.mark.parametrize("ps", [4, 8])
-@pytest.mark.parametrize("hd", [16, 32, 64])
-def test_paged_kernel_parity_mxfp4(ps, hd):
+@pytest.mark.parametrize("hd,ps,lengths,pages_per_slot", [
+    *[pytest.param(hd, ps, [9, 3 * ps, 1], None, id=f"{hd}-{ps}")
+      for hd in (16, 32, 64) for ps in (4, 8)],
+    pytest.param(32, 16, LONG_WALK[16], LONG_PAGES, id="32-16-long-walk"),
+])
+def test_paged_kernel_parity_mxfp4(hd, ps, lengths, pages_per_slot):
     """mxfp4 pool: the kernel's in-tile dequant must reproduce the jnp
     dequantize-then-attend reference on the identical packed payload; the
     result must also stay close to attention over the original fp values."""
-    lengths = [9, 3 * ps, 1]
-    out, ref, _ = _run_both("mxfp4", lengths, ps, Hkv=2, group=2, hd=hd)
+    out, ref, _ = _run_both("mxfp4", lengths, ps, Hkv=2, group=2, hd=hd,
+                            pages_per_slot=pages_per_slot)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=0, atol=1e-5)
 
 
 @pytest.mark.kernels
-@pytest.mark.parametrize("mode", ["dense", "mxfp4"])
-@pytest.mark.parametrize("S", [2, 4])
-def test_paged_kernel_multi_query_parity(mode, S):
+@pytest.mark.parametrize("S,mode,ps,lengths", [
+    *[pytest.param(S, mode, 4, [6, 1, 9], id=f"{S}-{mode}")
+      for S in (2, 4) for mode in ("dense", "mxfp4")],
+    # several blocks of 16 pages; the last slot's last row sees max_len
+    pytest.param(4, "mxfp4", 16, [500, 1, 637], id="4-mxfp4-long-walk"),
+])
+def test_paged_kernel_multi_query_parity(S, mode, ps, lengths):
     """Speculative-verify shape: S consecutive queries per slot with per-row
     causal bounds (row s at absolute position lengths[b]-1+s) must match the
-    blocked reference with per-row positions over the same ragged batch."""
-    ps, Hkv, group, hd = 4, 2, 2, 32
-    lengths = [6, 1, 9]  # first-query visible lengths (ragged, incl. fresh slot)
+    blocked reference with per-row positions over the same ragged batch
+    (``lengths``: first-query visible lengths, incl. a fresh slot)."""
+    Hkv, group, hd = 2, 2, 32
     pages_per_slot = max(-(-(max(lengths) + S - 1) // ps), 2)
     written = [l + S - 1 for l in lengths]  # burst KV is written before reading
     pool, tables, k, v = _paged_setup(mode, written, ps, Hkv, hd,
@@ -172,15 +225,19 @@ def test_prefill_chunk_layout_write_masking():
 
 
 @pytest.mark.kernels
-@pytest.mark.parametrize("mode", ["dense", "mxfp4"])
-def test_paged_kernel_batched_prefill_parity(mode):
+@pytest.mark.parametrize("mode,ps,starts", [
+    pytest.param("dense", 4, [4, 0, 9], id="dense"),
+    pytest.param("mxfp4", 4, [4, 0, 9], id="mxfp4"),
+    # several blocks of 16 pages, the first slot ending inside its second
+    pytest.param("mxfp4", 16, [420, 0, 250], id="mxfp4-long-walk"),
+])
+def test_paged_kernel_batched_prefill_parity(mode, ps, starts):
     """Batched-prefill shape: C queries per slot at per-slot start offsets
     with ragged valid counts.  Valid rows must match the blocked reference
     with per-row positions; padding rows scatter only to the scratch page
     (every real pool page is bit-identical to a run that wrote valid tokens
     only)."""
-    ps, Hkv, group, hd, C = 4, 2, 2, 32, 6
-    starts = [4, 0, 9]
+    Hkv, group, hd, C = 2, 2, 32, 6
     n_valid = [6, 3, 1]  # full chunk / ragged tail / single-token remainder
     B = len(starts)
     written = [s + n for s, n in zip(starts, n_valid)]
@@ -190,7 +247,7 @@ def test_paged_kernel_batched_prefill_parity(mode):
     k = jnp.asarray(rng.standard_normal((B, T, Hkv, hd)).astype(np.float32))
     v = jnp.asarray(rng.standard_normal((B, T, Hkv, hd)).astype(np.float32))
 
-    # context prefix (positions < start) written token-by-token, engine-style
+    # context prefix (positions < start) written engine-style
     n_pages = 1 + B * pages_per_slot
     pool = _empty_pool(mode, n_pages, ps, Hkv, hd)
     tables = np.zeros((B, pages_per_slot), np.int32)
@@ -200,10 +257,7 @@ def test_paged_kernel_batched_prefill_parity(mode):
             tables[b, p] = nxt
             nxt += 1
     tables = jnp.asarray(tables)
-    for b in range(B):
-        for t in range(starts[b]):
-            pool = PA.scatter_token(pool, tables[b, t // ps][None],
-                                    jnp.array([t % ps]), k[b, t][None], v[b, t][None])
+    pool = _scatter_prefix(pool, tables, k, v, starts, ps)
 
     # the chunk itself goes through the batched layout: padding tokens carry
     # garbage K/V that must only ever reach the scratch page
@@ -222,12 +276,8 @@ def test_paged_kernel_batched_prefill_parity(mode):
                             jnp.asarray(ck), jnp.asarray(cv))
 
     # write-masking conservation: non-scratch pages match a valid-only write
-    pool_ref = _empty_pool(mode, n_pages, ps, Hkv, hd)
-    for b in range(B):
-        for t in range(written[b]):
-            pool_ref = PA.scatter_token(pool_ref, tables[b, t // ps][None],
-                                        jnp.array([t % ps]), k[b, t][None],
-                                        v[b, t][None])
+    pool_ref = _scatter_prefix(_empty_pool(mode, n_pages, ps, Hkv, hd),
+                               tables, k, v, written, ps)
     for key in pool:
         np.testing.assert_array_equal(np.asarray(pool[key][1:]),
                                       np.asarray(pool_ref[key][1:]))
@@ -292,6 +342,64 @@ def test_paged_kernel_ignores_unmapped_pages():
                             causal=True, kv_chunk=ps)[:, 0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("n_q", [1, 3])
+def test_walk_pages_reads_live_pages_only(n_q):
+    """Each block of the walk names only the slot's live pages, every live
+    page once in order, and blocks past the last live one repeat it (so the
+    pipeline fetches nothing there)."""
+    ps, ppb, n_pp = 4, 4, 10
+    lengths = np.asarray([1, 13, 16, 38])
+    tables = np.arange(1, 1 + 4 * n_pp, dtype=np.int32).reshape(4, n_pp)
+    walk = np.asarray(PA.walk_pages(jnp.asarray(tables), jnp.asarray(lengths, jnp.int32),
+                                    n_q, ps, ppb)).reshape(4, -1, ppb)
+    assert walk.shape[1] == -(-n_pp // ppb)
+    for b, ln in enumerate(lengths):
+        n_live = -(-(ln + n_q - 1) // ps)
+        n_blk = -(-n_live // ppb)
+        live = tables[b, :n_live]
+        assert set(walk[b].ravel()) <= set(live)
+        assert walk[b, :n_blk].ravel()[:n_live].tolist() == live.tolist()
+        assert (walk[b, n_blk:] == walk[b, n_blk - 1]).all()
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("mode", ["dense", "mxfp4"])
+def test_paged_kernel_never_reads_dead_pages(mode):
+    """The walk stops at each slot's live pages.  Every dead column of the
+    page tables points at a page whose values decode to NaN/Inf, and the
+    output still equals the reference: a fetched dead page would reach acc
+    through p·v (0 · Inf is NaN).  The walk crosses blocks of 16 pages, ends
+    inside one, skips whole dead blocks, holds a slot at length 1, and runs
+    S = 1 decode and S = 3 verify bounds.  The same page on a live column
+    does poison the output."""
+    ps, Hkv, group, hd = 16, 2, 2, 32
+    lengths = [600, 1, 300, 16]
+    B, Hq = len(lengths), Hkv * group
+    for S in (1, 3):
+        written = [l + S - 1 for l in lengths]
+        pool, tables, k, v = _paged_setup(mode, written, ps, Hkv, hd,
+                                          LONG_PAGES, seed=5)
+        poison = next(iter(pool.values())).shape[0]  # one page past the pool
+        bad = {"k": np.nan, "v": np.nan, "k_codes": 0x77, "v_codes": 0x77,
+               "k_scales": 255, "v_scales": 255}  # E8M0 255 decodes to Inf
+        pool = {n: jnp.concatenate([x, jnp.full((1, *x.shape[1:]), bad[n], x.dtype)])
+                for n, x in pool.items()}
+        live = np.asarray([-(-w // ps) for w in written])
+        cols = np.arange(LONG_PAGES)[None, :]
+        tables = jnp.where(jnp.asarray(cols < live[:, None]), tables, poison)
+        rng = np.random.default_rng(55 + S)
+        q = jnp.asarray(rng.standard_normal((B, S, Hq, hd)), jnp.float32)
+        ln = jnp.asarray(lengths, jnp.int32)
+        out = PA.paged_attention(q, pool, tables, ln)
+        pos = (ln[:, None] - 1) + jnp.arange(S)[None, :]
+        ref = blocked_attention(q, k, v, pos, causal=True, kv_chunk=ps,
+                                shared_mask=False)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=0, atol=1e-5)
+    hit = PA.paged_attention(q, pool, tables.at[0, 0].set(poison), ln)
+    assert not np.isfinite(np.asarray(hit[0])).all()
 
 
 @pytest.mark.kernels

@@ -4,7 +4,7 @@ No chip is needed: the TPU compiler is installed with jaxlib, and
 ``topologies.get_topology_desc`` describes a ``v5e:2x2`` host that is not
 attached.  Each case lowers one kernel with ``interpret=False`` at
 qwen3-1.7b geometry (16 query heads, 8 KV heads, head dim 128, page 16,
-8 slots of 1024 tokens) and asserts that the compiled program holds the
+8 slots of 1024 tokens, and the benchmark cells' 3072 and 4096) and asserts that the compiled program holds the
 Mosaic kernel (``tpu_custom_call``).  What the chip's compiler refuses —
 block shapes off the (8, 128) tiling, casts Mosaic has no rule for — fails
 here, at no chip time.
@@ -55,13 +55,13 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _pool_specs(kind, sharding):
+def _pool_specs(kind, sharding, n_pages=N_PAGES):
     if kind == "dense":
-        shape = (N_PAGES, HKV, PS, HD)
+        shape = (n_pages, HKV, PS, HD)
         return {"k": _spec(shape, jnp.bfloat16, sharding),
                 "v": _spec(shape, jnp.bfloat16, sharding)}
     nb = HD // PA.quant_block(HD)
-    codes, scales = (N_PAGES, HKV, PS, HD // 2), (N_PAGES, HKV, PS, nb)
+    codes, scales = (n_pages, HKV, PS, HD // 2), (n_pages, HKV, PS, nb)
     return {"k_codes": _spec(codes, jnp.uint8, sharding),
             "k_scales": _spec(scales, jnp.uint8, sharding),
             "v_codes": _spec(codes, jnp.uint8, sharding),
@@ -72,15 +72,22 @@ def _assert_mosaic(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("kind", ["mxfp4", "dense"])
-@pytest.mark.parametrize("n_q", [1, 16])
-def test_paged_attention_compiles_for_v5e(one_chip, kind, n_q):
+@pytest.mark.parametrize("n_q,kind,pages", [
+    *[pytest.param(n_q, kind, PAGES_PER_SLOT, id=f"{n_q}-{kind}")
+      for n_q in (1, 16) for kind in ("mxfp4", "dense")],
+    # the benchmark cells' tables: chat 3072 / 16 = 192 columns, rag's
+    # prefill 4096 / 16 + the sentinel = 257; decode and prefill chunk rows
+    *[pytest.param(n_q, kind, pages, id=f"{n_q}-{kind}-{pages}")
+      for pages in (192, 257) for n_q in (1, 128) for kind in ("mxfp4", "dense")],
+])
+def test_paged_attention_compiles_for_v5e(one_chip, n_q, kind, pages):
     q_shape = (B, HQ, HD) if n_q == 1 else (B, n_q, HQ, HD)
     fn = jax.jit(lambda q, pool, tables, lengths: PA.paged_attention(
         q, pool, tables, lengths, interpret=False))
     compiled = fn.lower(
-        _spec(q_shape, jnp.bfloat16, one_chip), _pool_specs(kind, one_chip),
-        _spec((B, PAGES_PER_SLOT), jnp.int32, one_chip),
+        _spec(q_shape, jnp.bfloat16, one_chip),
+        _pool_specs(kind, one_chip, 1 + B * pages),
+        _spec((B, pages), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip)).compile()
     _assert_mosaic(compiled)
 

@@ -10,7 +10,8 @@ Pinned here:
 * the engine's span names start with ``serve.`` and none is a name the
   benchmark's trace reduction reserves for the harness,
 * one decode ``Engine.step`` emits the decode span set; prefill dispatch
-  spans count ``rows_valid <= rows_computed``, as the counters do,
+  spans count ``rows_valid <= rows_computed``, as the counters do, and
+  every paged dispatch counts the pages its kernel walks,
 * the per-phase tick histograms are the spans' durations,
 * a token is stamped when it is emitted: a step of known length shows in
   ``ttft_s``.
@@ -137,7 +138,10 @@ def test_one_decode_step_emits_the_decode_set(dense_setup):
     step = next(s for s in got if s.name == "serve.step")
     assert step.attrs == {"step": 2, "queued": 0, "prefilling": 0, "decoding": 1}
     by = {s.name: s for s in got}
-    assert by["serve.decode.dispatch"].attrs == {"slots": 1}
+    # the 6 tokens the decode query sees fill one page of 8; the tables hold
+    # 2 slots x ceil(48 / 8) columns
+    assert by["serve.decode.dispatch"].attrs == {"slots": 1, "pages_live": 1,
+                                                  "page_columns": 12}
     vocab_f32 = 2 * cfg.vocab_size * 4  # [n_slots, V] float32 logits
     assert by["serve.decode.fetch"].attrs == {"bytes": vocab_f32}
     assert by["serve.decode"].parent == step.sid
@@ -150,6 +154,11 @@ def test_prefill_rows_and_counters(dense_setup):
     got = _run(eng, cfg)
     calls = [s.attrs for s in got if s.name == "serve.prefill.dispatch"]
     assert calls and all(0 < a["rows_valid"] <= a["rows_computed"] == 2 * 8 for a in calls)
+    # a chunk of 8 rows from position p walks ceil((p + 8) / 8) pages: the
+    # prompts of 5 and 12 tokens start together (1 + 1), the 12 goes on from
+    # 8 (2), and the 19, admitted once the 5 retires, from 0, 8 and 16
+    assert [(a["slots"], a["pages_live"], a["page_columns"]) for a in calls] == [
+        (2, 2, 12), (1, 2, 12), (1, 1, 12), (1, 2, 12), (1, 3, 12)]
     c = eng.telemetry.registry.counter
     assert sum(a["rows_valid"] for a in calls) == c("prompt_tokens_prefilled").value
     assert sum(a["rows_computed"] for a in calls) == c("prefill_rows_computed").value
