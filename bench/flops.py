@@ -11,6 +11,11 @@ from __future__ import annotations
 
 import math
 
+# The dims of a dense decoder with full-context attention, all that these
+# counts know.  Dims that declare more (experts, windows) are not counted here.
+DENSE_DIMS = frozenset({"d", "f", "L", "hq", "hkv", "hd", "V", "theta", "eps", "norm",
+                        "qk_norm", "mlp", "bias", "tied"})
+
 
 def linear_params(m: dict) -> int:
     """Weights of the transformer linears (all layers; no embedding or head)."""

@@ -32,22 +32,23 @@ TRACE_SECONDS = 6.0  # longest traced window
 
 
 def program_config(config: dict):
-    """The program's ModelConfig for a configuration file, checked against
-    the file's published sizes."""
+    """The program's ModelConfig for a configuration file, checked field by
+    field against what the configuration's reference says the published
+    architecture requires (its ``program_fields``)."""
     from repro.configs import get_config
 
     prog = config["program"]
     cfg = get_config(prog["arch"], **prog.get("overrides", {}))
-    m = common.reference_module(config).dims(config)
-    want = {"d_model": m["d"], "d_ff": m["f"], "num_layers": m["L"],
-            "num_heads": m["hq"], "num_kv_heads": m["hkv"], "head_dim_": m["hd"],
-            "vocab_size": m["V"], "rope_theta": m["theta"], "norm_eps": m["eps"],
-            "norm": m["norm"], "qk_norm": m["qk_norm"], "mlp": m["mlp"],
-            "use_bias": m["bias"], "tie_embeddings": m["tied"], "family": "dense"}
-    got = {k: getattr(cfg, k) for k in want}
+    ref = common.reference_module(config)
+    if not hasattr(ref, "program_fields"):
+        raise ValueError(f"reference {config['reference']!r} exports no program_fields, "
+                         "so the program cannot be checked against it")
+    want = ref.program_fields(config)
+    got = {k: getattr(cfg, k, "<no such attribute>") for k in want}
     bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
     if bad:
-        raise ValueError(f"program config differs from {prog['arch']}'s file: {bad}")
+        raise ValueError(f"program config differs from {prog['arch']}'s file "
+                         f"(program, published): {bad}")
     return cfg
 
 

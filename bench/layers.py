@@ -73,7 +73,20 @@ def _per_call_ratio(ctx, module, work_per_step, host_calls):
     return work, dev_s
 
 
+def counted(ctx: Context, metric: str) -> bool:
+    """Whether ``bench/flops.py`` can count the configuration's work; where
+    its dims declare more than a dense full-attention decoder, a note says
+    why the metric is left out."""
+    extra = sorted(set(ctx.m) - flops.DENSE_DIMS)
+    if extra:
+        ctx.note(f"{metric}_not_counted",
+                 f"dims {extra}: bench/flops.py counts only a dense full-attention decoder")
+    return not extra
+
+
 def decode_mfu(ctx: Context) -> float | None:
+    if not counted(ctx, "decode_mfu"):
+        return None
     steps = [s for s in ctx.steps if s.decode_contexts]
     work, dev_s = _per_call_ratio(
         ctx, DECODE, [flops.decode_call_flops(ctx.m, s.decode_contexts) for s in steps], steps)
@@ -81,6 +94,8 @@ def decode_mfu(ctx: Context) -> float | None:
 
 
 def prefill_mfu(ctx: Context) -> float | None:
+    if not counted(ctx, "prefill_mfu"):
+        return None
     steps = [s for s in ctx.steps if s.prefill_rows]
     work, dev_s = _per_call_ratio(
         ctx, PREFILL, [flops.prefill_call_flops(ctx.m, s.prefill_rows, s.prefill_finished)
@@ -91,6 +106,8 @@ def prefill_mfu(ctx: Context) -> float | None:
 def paged_attention_roofline(ctx: Context) -> float | None:
     """Least time of the kernel's work (live pages, q, out) over its summed
     device time inside ``decode_all``."""
+    if not counted(ctx, "paged_attention_roofline"):
+        return None
     steps = [s for s in ctx.steps if s.decode_contexts]
     calls = ctx.calls(DECODE)
     kern = [xtrace.ops_in_calls(ctx.timeline, d, c, is_paged_attention)

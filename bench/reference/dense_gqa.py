@@ -15,6 +15,10 @@ layout (the input format of the system under test); the reference and the
 program both read those.  ``scorer(..., fp8=True)`` is the lower-precision
 control: the same forward with every linear's operands rounded to
 float8_e4m3 (per-row activation scale, per-column weight scale).
+``program_fields`` is what the program's ModelConfig must hold for the
+configuration, checked before any weight is drawn.
+
+Other references (``moe_gqa.py``) import the pieces they share from here.
 """
 
 from __future__ import annotations
@@ -44,14 +48,29 @@ def dims(c: dict) -> dict:
             "bias": a["bias"], "tied": c["tie_word_embeddings"]}
 
 
+def fields_of(m: dict) -> dict:
+    """The program ModelConfig attributes a decoder of dims ``m`` sets, but
+    its family."""
+    return {"d_model": m["d"], "d_ff": m["f"], "num_layers": m["L"],
+            "num_heads": m["hq"], "num_kv_heads": m["hkv"], "head_dim_": m["hd"],
+            "vocab_size": m["V"], "rope_theta": m["theta"], "norm_eps": m["eps"],
+            "norm": m["norm"], "qk_norm": m["qk_norm"], "mlp": m["mlp"],
+            "use_bias": m["bias"], "tie_embeddings": m["tied"]}
+
+
+def program_fields(c: dict) -> dict:
+    """Program ModelConfig attribute -> the value the published configuration
+    requires."""
+    return {**fields_of(dims(c)), "family": "dense"}
+
+
 # ---------------------------------------------------------------------------
 # weights, in the program's parameter layout
 # ---------------------------------------------------------------------------
 
 
-def param_shapes(c: dict) -> dict:
-    """Nested dict of leaf shapes; layer leaves carry a leading [L]."""
-    m = dims(c)
+def param_shapes(m: dict) -> dict:
+    """Nested dict of leaf shapes for dims ``m``; layer leaves carry a leading [L]."""
     d, f, L, hd = m["d"], m["f"], m["L"], m["hd"]
 
     def lin(i, o):
@@ -104,7 +123,11 @@ def seed_key(seed: int):
 def make_params(c: dict, seed: int):
     """All weights from ``seed``, in bf16 as they are served, on the device,
     in one jitted call."""
-    shapes = param_shapes(c)
+    return draw(param_shapes(dims(c)), seed)
+
+
+def draw(shapes: dict, seed: int):
+    """Leaves of ``shapes`` drawn from ``seed`` by ``_leaf_init``, in bf16."""
     flat = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))[0]
 
@@ -131,7 +154,7 @@ def make_params(c: dict, seed: int):
 # ---------------------------------------------------------------------------
 
 
-def _norm(p, x, eps):
+def apply_norm(p, x, eps):
     if "bias" in p:
         mu = jnp.mean(x, -1, keepdims=True)
         var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
@@ -146,7 +169,7 @@ def fp8_round(x, axis):
     return (x / s).astype(jnp.float8_e4m3fn).astype(F32) * s
 
 
-def _linear(p, x, fp8: bool):
+def linear(p, x, fp8: bool):
     w = p["w"]
     if fp8:
         x, w = fp8_round(x, -1), fp8_round(w, 0)
@@ -197,31 +220,35 @@ def _attention(q, k, v):
     return out.reshape(T, hq * hd)
 
 
-def _block(m, lp, x, pos, fp8):
-    lp = jax.tree.map(lambda a: a.astype(F32), lp)
+def attend(m, lp, x, pos, fp8):
+    """The residual stream after a block's attention; ``lp`` in float32."""
     T = x.shape[0]
-    h = _norm(lp["attn_norm"], x, m["eps"])
+    h = apply_norm(lp["attn_norm"], x, m["eps"])
     a = lp["attn"]
-    q = _linear(a["wq"], h, fp8).reshape(T, m["hq"], m["hd"])
-    k = _linear(a["wk"], h, fp8).reshape(T, m["hkv"], m["hd"])
-    v = _linear(a["wv"], h, fp8).reshape(T, m["hkv"], m["hd"])
+    q = linear(a["wq"], h, fp8).reshape(T, m["hq"], m["hd"])
+    k = linear(a["wk"], h, fp8).reshape(T, m["hkv"], m["hd"])
+    v = linear(a["wv"], h, fp8).reshape(T, m["hkv"], m["hd"])
     if m["qk_norm"]:
-        q, k = _norm(a["q_norm"], q, m["eps"]), _norm(a["k_norm"], k, m["eps"])
+        q, k = apply_norm(a["q_norm"], q, m["eps"]), apply_norm(a["k_norm"], k, m["eps"])
     q, k = _rope(q, pos, m["theta"]), _rope(k, pos, m["theta"])
     k, v = mxfp4_round(k), mxfp4_round(v)
-    x = x + _linear(a["wo"], _attention(q, k, v), fp8)
-    h = _norm(lp["mlp_norm"], x, m["eps"])
+    return x + linear(a["wo"], _attention(q, k, v), fp8)
+
+
+def _block(m, lp, x, pos, fp8):
+    lp = jax.tree.map(lambda a: a.astype(F32), lp)
+    x = attend(m, lp, x, pos, fp8)
+    h = apply_norm(lp["mlp_norm"], x, m["eps"])
     mp = lp["mlp"]
     if m["mlp"] == "swiglu":
-        u = jax.nn.silu(_linear(mp["gate"], h, fp8)) * _linear(mp["up"], h, fp8)
+        u = jax.nn.silu(linear(mp["gate"], h, fp8)) * linear(mp["up"], h, fp8)
     else:
-        u = jax.nn.gelu(_linear(mp["up"], h, fp8), approximate=True)
-    return x + _linear(mp["down"], u, fp8)
+        u = jax.nn.gelu(linear(mp["up"], h, fp8), approximate=True)
+    return x + linear(mp["down"], u, fp8)
 
 
-@functools.lru_cache(maxsize=None)
-def _scorer(c_json: str, fp8: bool):
-    m = json.loads(c_json)
+def score_fn(m: dict, fp8: bool, block):
+    """``score`` over a stack of ``block(m, lp, x, pos, fp8)``, un-jitted."""
 
     def score(params, tokens, query):
         """tokens [T], query [T, K] -> (best [T], logit of each query [T, K],
@@ -229,10 +256,10 @@ def _scorer(c_json: str, fp8: bool):
         T = tokens.shape[0]
         pos = jnp.arange(T)
         x = params["embed"]["table"][tokens].astype(F32)
-        x, _ = jax.lax.scan(lambda x, lp: (_block(m, lp, x, pos, fp8), None),
+        x, _ = jax.lax.scan(lambda x, lp: (block(m, lp, x, pos, fp8), None),
                             x, params["layers"])
         fn = jax.tree.map(lambda a: a.astype(F32), params["final_norm"])
-        h = _norm(fn, x, m["eps"])
+        h = apply_norm(fn, x, m["eps"])
         w = (params["embed"]["table"].T if m["tied"] else params["lm_head"]["w"]).astype(F32)
         if fp8:
             w = fp8_round(w, 0)
@@ -250,7 +277,12 @@ def _scorer(c_json: str, fp8: bool):
             rows, (h.reshape(n, ROW_BLOCK, -1), query.reshape(n, ROW_BLOCK, -1)))
         return best.reshape(T), vals.reshape(T, -1), top.reshape(T)
 
-    return jax.jit(score)
+    return score
+
+
+@functools.lru_cache(maxsize=None)
+def _scorer(c_json: str, fp8: bool):
+    return jax.jit(score_fn(json.loads(c_json), fp8, _block))
 
 
 def scorer(c: dict, fp8: bool = False):

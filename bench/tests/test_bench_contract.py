@@ -84,3 +84,5 @@ def test_config_files_hold_what_the_harness_reads(c):
     assert cfg["min_scored_tokens"] >= 100
     ref = common.reference_module(cfg)
     assert ref.dims(cfg)["d"] == cfg["hidden_size"]
+    assert callable(getattr(ref, "program_fields", None))
+    harness.program_config(cfg)  # the program holds what the file publishes

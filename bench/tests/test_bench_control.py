@@ -3,6 +3,8 @@ worse than the program, and come out not correct through the harness's own
 decision, at a size a test can hold.  Requests are served to completion (no
 clock), so the readings repeat exactly."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -10,28 +12,19 @@ from bench import common, harness
 from bench.tests import small
 
 
-def served(cell, seed):
-    _, params, engine = harness.build(cell.config, seed)
-    rng = np.random.default_rng(seed)
-    recs = [harness.Rec(req=engine.submit(rng.integers(0, 512, n).astype(np.int32), 24),
-                        due=0.0, submitted=0.0, done_at=0.0)
-            for n in (17, 40, 64, 90, 121, 33, 77, 100)]
-    engine.drain()
-    return params, recs
-
-
+@functools.lru_cache(maxsize=None)
 def readings(name, seeds=(1, 2, 3)):
-    cell = small.small_cell(name)
+    cell = small.cell(name)
     prog, ctrl = [], []
     for seed in seeds:
-        params, recs = served(cell, seed)
+        params, recs = small.served(cell, seed)
         g = harness.logit_gaps(cell.config, params, recs, control=True)
         prog.append(harness.gap_stats(g["gap"]))
         ctrl.append(harness.gap_stats(g["control_gap"]))
     return prog, ctrl
 
 
-@pytest.mark.parametrize("name", ["qwen3-1.7b", "starcoder2-3b"])
+@pytest.mark.parametrize("name", ["qwen3-1.7b", "starcoder2-3b", "small_moe"])
 def test_control_reads_worse_than_the_program(name):
     prog, ctrl = readings(name)
     for key in ("logit_gap_mean", "logit_gap_p99"):
@@ -45,6 +38,15 @@ def test_control_is_not_correct_through_the_harness_decision():
     control 0.036-0.051)."""
     prog, ctrl = readings("qwen3-1.7b")
     config = {**small.small_config(), "limits": {"logit_gap_mean": 0.02}}
+    assert all(harness.decide(p, config)[0] for p in prog), prog
+    assert not any(harness.decide(c, config)[0] for c in ctrl), ctrl
+
+
+def test_moe_control_is_not_correct_through_the_harness_decision():
+    """The small MoE cell at the limit its file states (0.16, set from its
+    readings: program 0.045-0.124, control 0.193-0.365 over seeds 1-15)."""
+    prog, ctrl = readings("small_moe")
+    config = small.moe_cell().config
     assert all(harness.decide(p, config)[0] for p in prog), prog
     assert not any(harness.decide(c, config)[0] for c in ctrl), ctrl
 

@@ -16,9 +16,9 @@ def run(cell, seed=2**31 + 17, seconds=2.0):
                             peak=small.CPU_PEAK)
 
 
-@pytest.mark.parametrize("name", ["qwen3-1.7b", "starcoder2-3b"])
+@pytest.mark.parametrize("name", ["qwen3-1.7b", "starcoder2-3b", "small_moe"])
 def test_run_is_correct_and_reports_its_metrics(name):
-    result, check = run(small.small_cell(name))
+    result, check = run(small.cell(name))
     assert result["correct"], check
     assert set(result["metrics"]) == {"output_tok_s", "itl_p95_ms", "setup_s"}
     assert all(v["value"] > 0 for v in result["metrics"].values())
@@ -40,6 +40,18 @@ def test_altered_token_is_caught(monkeypatch):
 
     monkeypatch.setattr(Engine, "_sample", runner_up)
     result, check = run(small.small_cell())
+    assert not result["correct"], check
+
+
+def test_moe_altered_token_is_caught(monkeypatch):
+    """The same fault in the small routed-expert cell (reference ``moe_gqa``)."""
+    from repro.serve.engine import Engine
+
+    def runner_up(self, req, logits_row, token_idx):
+        return int(np.argsort(np.asarray(logits_row))[-2])
+
+    monkeypatch.setattr(Engine, "_sample", runner_up)
+    result, check = run(small.moe_cell())
     assert not result["correct"], check
 
 

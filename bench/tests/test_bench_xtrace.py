@@ -118,6 +118,18 @@ def test_readers_find_nothing_and_say_so():
     assert layers.mean_call_ms(ctx, layers.DECODE) is None
 
 
+@pytest.mark.parametrize("read", [layers.decode_mfu, layers.prefill_mfu,
+                                  layers.paged_attention_roofline], ids=lambda f: f.__name__)
+def test_counting_readers_leave_out_moe_dims(tl, read):
+    """``bench/flops.py`` counts a dense decoder: with routed experts in the
+    dims a counting reader reads nothing, where the trace has its calls."""
+    ctx, _, _ = _ctx(tl)
+    moe = common.load_json(small.SMALL_MOE)
+    ctx.m = common.reference_module(moe).dims(moe)
+    assert read(ctx) is None
+    assert "['E', 'k', 'norm_topk']" in ctx.notes[f"{read.__name__}_not_counted"]
+
+
 def test_every_metric_file_reads_through_read_all(tl):
     ctx, _, _ = _ctx(tl)
     spec = common.load_json(common.ROOT / "BENCHMARK.json")
